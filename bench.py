@@ -3155,20 +3155,34 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
                         timeout: float = 120.0) -> dict:
     """detail.topology.lease (round 23) — ELASTIC membership under
     in-worker chaos, riding the main arm's tile/config/compile-cache:
-    2 lease-mode workers bootstrap over a 4-partition broker through
-    the epoch-fenced lease table (distributed/lease.py), a cold worker
-    JOINS mid-soak (supervisor rebalance → revoke toward the newcomer
-    → adoption at committed floors), a leased worker takes a SIGKILL
-    (lease expiry → orphan → reassignment), and worker lease-a runs an
-    RTPU_FAULTS plan INSIDE itself (publisher faults the retry
-    machinery absorbs + an injected mid-checkpoint crash that kills the
-    process hard). Asserted: join→first-acquire and kill→reacquire
-    latency, fencing (the killed worker's stale-epoch commit rejected),
-    offset-granularity conservation (floors reach end offsets with
-    commit spans never overlapping — zero lost, zero duplicated), and
-    per-worker fault stats surfaced through the snapshot gauges (the
-    crashed incarnation prints no exit report — the spool is the
-    surviving record)."""
+    lease-mode workers over a 4-partition broker through the
+    epoch-fenced lease table (distributed/lease.py). lease-a bootstraps
+    alone and takes every partition, and runs an RTPU_FAULTS plan
+    INSIDE itself (dispatch hangs, then an injected mid-checkpoint
+    crash that kills the process hard); lease-b joins and inherits the
+    table; a cold lease-c JOINS mid-soak (supervisor rebalance →
+    revoke toward the newcomer → adoption at committed floors); a
+    leased lease-b takes a SIGKILL (lease expiry → orphan →
+    reassignment); and lease-c, the survivor, drains the whole broker
+    and exits on the GLOBAL drain (--exit-on-drain: table floors over
+    every partition, sub-threshold tails published by the in-loop
+    force-flush stall escape). Asserted: join→first-acquire and
+    kill→reacquire latency, fencing (the killed worker's stale-epoch
+    commit rejected), offset-granularity conservation (floors reach end
+    offsets with commit spans never overlapping — zero lost, zero
+    duplicated), the topology drained by its own exits, and per-worker
+    fault stats surfaced through the snapshot gauges (the crashed
+    incarnation prints no exit report — the spool is the surviving
+    record).
+
+    Every step waits on what the table, the supervisor's event log or
+    the snapshot spool shows, never on a fixed delay, so the order
+    holds on a loaded host. lease-a and lease-b serve until they die
+    (an exit-on-drain there could end either before its death);
+    neither flushes a sub-threshold tail, so while lease-b lives some
+    floor stays below its end offset and lease-c cannot exit early,
+    and lease-b is killed holding such a partition, which no live
+    worker but lease-c can then adopt."""
     from reporter_tpu.distributed import Supervisor, worker_member
     from reporter_tpu.distributed.lease import LeaseTable, StaleLeaseError
     from reporter_tpu.streaming.durable_queue import DurableIngestQueue
@@ -3182,6 +3196,7 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
     # ttl makes every startup a lease-loss storm (measured at 1.2 s:
     # 12 lease_lost events, ~12 s of discard/reconsume churn)
     ttl_s = 2.4
+
     def _stage(cyc: int) -> "list[int]":
         # reopen-append: the durable log continues its offsets, so a
         # mid-soak tranche is indistinguishable from a live producer
@@ -3201,33 +3216,30 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
     produced = cycles * per_cycle
     end_offsets = _stage(0)
     table = LeaseTable(lease_dir, num_partitions=4, ttl_s=ttl_s)
-    # dispatch hangs at calls 1-2 are the RECOVERABLE chaos: the site
-    # fires at every flush wave (the first lands ~1 s in — reports and
-    # therefore the publish site only materialize near drain, far too
-    # late for a worker that dies mid-run), a hang is a plain sleep
-    # with the watchdog off, and the fired count spools well before
-    # the crash; the first checkpoint call at index >= 4 then raises
-    # InjectedCrash → the CLI dies via os._exit(17), no exit report —
-    # the snapshot spool is the surviving record. The window is
-    # open-ended on purpose: checkpoint calls are wall-clock gated, so
-    # one-core flush stalls consolidate gate openings and a fixed high
-    # index is intermittently never reached before drain; call 3 lands
-    # inside the first hang iteration, so >= 4 guarantees one full
-    # snapshot-spooling iteration after the first dispatch fire.
-    fault_spec = "dispatch:hang(0.6)@1-3;checkpoint:crash@4-"
+    # dispatch hangs at calls 0-1 are the RECOVERABLE chaos (a hang is
+    # a plain sleep with the watchdog off); the first checkpoint call
+    # at index >= 4 then raises InjectedCrash → the CLI dies via
+    # os._exit(17), no exit report — the snapshot spool is the
+    # surviving record. lease-a holds every partition from its first
+    # sync, so it dispatches within its first steps (each uuid reaches
+    # flush_min_points inside the first tranche), well before
+    # checkpoint call 4, and the snapshot spool of that iteration
+    # carries the fired count. The crash window is open-ended:
+    # checkpoint calls are wall-clock gated and a worker that serves
+    # until it dies always reaches one.
+    fault_spec = "dispatch:hang(0.6)@0-2;checkpoint:crash@4-"
 
-    def _member(name: str, env: "dict | None" = None):
+    def _member(name: str, env: "dict | None" = None,
+                exit_on_drain: bool = False):
         return worker_member(name, tiles_path, broker_dir, arm_dir,
-                             config=cfg_path, lease_dir=lease_dir,
-                             lease_ttl_s=ttl_s, env=env)
+                             config=cfg_path, exit_on_drain=exit_on_drain,
+                             lease_dir=lease_dir, lease_ttl_s=ttl_s,
+                             env=env)
 
-    members = [
-        # in-worker chaos rides MemberSpec.env: recoverable publisher
-        # faults + a mid-checkpoint InjectedCrash → os._exit(17)
-        _member("lease-a", env={"RTPU_FAULTS": fault_spec,
-                                "RTPU_FAULT_SEED": "11"}),
-        _member("lease-b"),
-    ]
+    # in-worker chaos rides MemberSpec.env: dispatch hangs + a
+    # mid-checkpoint InjectedCrash → os._exit(17)
+    members = [_member("lease-a", env={"RTPU_FAULTS": fault_spec,
+                                       "RTPU_FAULT_SEED": "11"})]
     # restart=False: an elastically-leased topology survives by
     # REBALANCING onto the survivors, not restart-in-place — dead
     # members' leases expire and their partitions move
@@ -3235,12 +3247,10 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
                      poll_s=0.05, lease_dir=lease_dir,
                      base_env={"JAX_PLATFORMS": "cpu",
                                "RTPU_TOPO_SNAPSHOT_INTERVAL_S": "0.3",
-                               # r24: the crash window (checkpoint:
-                               # crash@4-) is wall-clock tuned against
-                               # the r23 loop cost — an in-worker SLO
-                               # tick would shift which call lands in
-                               # the first hang iteration; SLO chaos
-                               # claims live in detail.slo
+                               # r24: an in-worker SLO tick would add
+                               # its own cost to every loop iteration
+                               # of the chaos worker; SLO chaos claims
+                               # live in detail.slo
                                "RTPU_SLO": "0"})
     note = None
     join_s = reacquire_s = None
@@ -3256,42 +3266,64 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
                 time.sleep(0.03)
             return False
 
-        # workers are up once the table shows lease activity — the
-        # startup acquire storm is the soak's first act (waiting for
-        # sink rows instead would idle through both workers' first-
-        # flush compile stalls)
-        if not _wait(lambda: any(e["event"] == "acquire"
-                                 for e in table.events()), 20.0):
-            note = "no lease activity"
-
-        # ---- mid-soak JOIN of a cold worker -------------------------
-        t_join = time.time()
-        sup.add_member(_member("lease-c"))
-
-        def _acquires_c():
+        def _acquires(member: str) -> "list[dict]":
             return [e for e in table.events()
                     if e["event"] == "acquire"
-                    and e.get("member") == "lease-c"]
+                    and e.get("member") == member]
 
-        # ---- second tranche lands while the join is still cold ------
-        # the SIGKILL below must never race a completed drain: fresh
-        # backlog guarantees the orphaned partition still has records
-        # for its next owner to serve, and keeps every survivor's loop
-        # alive through the whole choreography
-        for c2 in range(1, cycles):
-            end_offsets = _stage(c2)
-
-        # ---- SIGKILL of a leased worker + zombie fencing probe ------
-        def _owned_by_b():
+        def _owned_by(member: str) -> "list[tuple[int, int]]":
             return sorted(
                 (int(p), int(ent["epoch"]))
                 for p, ent in table.state()["partitions"].items()
-                if ent["owner"] == "lease-b")
+                if ent["owner"] == member)
 
-        _wait(lambda: bool(_owned_by_b()), 10.0)
-        owned_b = _owned_by_b()
-        if owned_b:
-            p_vic, epoch_vic = owned_b[0]
+        def _died(member: str) -> bool:
+            return any(e["event"] == "member_death"
+                       and e.get("member") == member
+                       for e in sup.events())
+
+        # ---- bootstrap: lease-a takes the table, lease-b joins ------
+        if not _wait(lambda: bool(_acquires("lease-a")), 30.0):
+            note = "lease-a never acquired"
+        sup.add_member(_member("lease-b"))
+        # the rebalancer revokes half toward lease-b — or, once lease-a
+        # has crashed, hands it lease-a's expired leases
+        if not _wait(lambda: bool(_owned_by("lease-b")), 30.0):
+            note = (note or "") + " lease-b never owned a partition"
+
+        # ---- lease-a's injected crash -------------------------------
+        # its leases expire and lease-b adopts them at their floors
+        if not _wait(lambda: _died("lease-a"), 30.0):
+            note = (note or "") + " lease-a never crashed"
+
+        # ---- second tranche, then a cold worker JOINS mid-soak ------
+        # the tranche lands before the join, so the end offsets lease-c
+        # drains toward are final from its first step on
+        for c2 in range(1, cycles):
+            end_offsets = _stage(c2)
+        t_join = time.time()
+        sup.add_member(_member("lease-c", exit_on_drain=True))
+        # lease-b lives and holds every partition, so the plan revokes
+        # half toward the newcomer
+        if _wait(lambda: bool(_acquires("lease-c")), 30.0):
+            join_s = round(max(0.0, _acquires("lease-c")[0]["t"]
+                               - t_join), 2)
+        else:
+            note = (note or "") + " join never acquired"
+
+        # ---- SIGKILL of a leased worker + zombie fencing probe ------
+        # the victim partition still has uncommitted records (lease-b
+        # never flushes its sub-threshold tails), so after the kill it
+        # is pinned until lease-c adopts it: the drain cannot end first
+        def _victim() -> "tuple[int, int] | None":
+            floors = table.floors()
+            return next(((p, e) for p, e in _owned_by("lease-b")
+                         if floors[p] < end_offsets[p]), None)
+
+        _wait(lambda: _victim() is not None, 30.0)
+        vic = _victim()
+        if vic is not None:
+            p_vic, epoch_vic = vic
             t_kill = time.time()
             sup.kill_member("lease-b")
 
@@ -3300,7 +3332,7 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
                 return (ent["owner"] not in (None, "lease-b")
                         and int(ent["epoch"]) > epoch_vic)
 
-            if _wait(_reacquired, 20.0):
+            if _wait(_reacquired, 30.0):
                 acq = [e for e in table.events()
                        if e["event"] == "acquire"
                        and e.get("partition") == p_vic
@@ -3319,17 +3351,12 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
             else:
                 note = (note or "") + " victim never reacquired"
         else:
-            note = (note or "") + " lease-b never owned a partition"
-
-        # ---- join latency: cold spawn → first acquire ---------------
-        # measured LAST so the wait overlaps the kill/fence work above
-        # (the joiner's first acquire usually lands during it)
-        if _wait(lambda: bool(_acquires_c()), 25.0):
-            join_s = round(max(0.0, _acquires_c()[0]["t"] - t_join), 2)
-        else:
-            note = (note or "") + " join never acquired"
+            note = (note or "") + " lease-b held no uncommitted partition"
 
         # ---- drain + offset-granularity conservation ----------------
+        # lease-c exits on the global drain; the topology has ended by
+        # its own exits once every member is down and the floors are
+        # at the end offsets
         def _drained():
             floors = table.floors()
             return (sup.drained()
@@ -3339,6 +3366,10 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
         if not _wait(_drained, timeout):
             note = (note or "") + " drain timed out"
         sup.poll_once()
+        # the survivor left on its own (exit code 0, not the stop below)
+        drained_by_exit = sup.drained() and any(
+            e["event"] == "member_exit" and e.get("member") == "lease-c"
+            and e.get("returncode") == 0 for e in sup.events())
         floors = table.floors()
         lost = sum(max(0, end_offsets[p] - floors[p]) for p in range(4))
         levents = table.events()
@@ -3364,13 +3395,14 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
         rebalances = sum(1 for e in sup.events()
                          if e["event"] == "rebalance")
         out = {
-            "config": (f"2+1 lease-mode CPU workers over 4 leased "
+            "config": (f"1+2 lease-mode CPU workers over 4 leased "
                        f"partitions ({produced} probes, ttl {ttl_s}s): "
-                       f"mid-soak join, SIGKILL lease-b, in-worker "
-                       f"chaos in lease-a"),
+                       f"lease-a bootstraps, lease-b then lease-c "
+                       f"join, SIGKILL lease-b, in-worker chaos in "
+                       f"lease-a, lease-c exits on the global drain"),
             "ttl_s": ttl_s,
-            "workers_start": 2,
-            "workers_joined": 1,
+            "workers_start": 1,
+            "workers_joined": 2,
             "broker_probes": int(produced),
             "deaths": int(health.get("deaths_total", 0)),
             "join_to_first_acquire_seconds": join_s,
@@ -3381,6 +3413,7 @@ def _topology_lease_arm(workdir: str, tiles_path: str, cfg_path: str,
             "zero_lost_ok": bool(lost == 0),
             "duplicate_commits": int(dup),
             "zero_dup_ok": bool(dup == 0),
+            "drained_by_exit_ok": bool(drained_by_exit),
             "commits": int(commits),
             "rebalances": int(rebalances),
             "fault_spec": fault_spec,
@@ -3515,16 +3548,30 @@ def _topology_bench(tpu_ok: bool, timeout: float = 420.0) -> dict:
         def _sink_rows() -> int:
             return sup.sink.stats()["rows"]
 
+        def _w0_lag() -> "int | None":
+            doc = sup.snapshots().get("worker-0") or {}
+            return (doc.get("stats") or {}).get("lag")
+
+        def _w0_exited_clean() -> bool:
+            return any(e["event"] == "member_exit"
+                       and e.get("member") == "worker-0"
+                       for e in sup.events())
+
         # ---- mid-soak SIGKILL of worker-0 ---------------------------
+        # triggered by worker-0's OWN progress: its first spooled
+        # snapshot (matcher built, first step served) with lag still
+        # outstanding. The first sink row is no such signal: it can come
+        # from worker-1 alone (on this fleet worker-1 publishes every
+        # report), and worker-0 could drain and exit clean before it.
         t0 = time.perf_counter()
-        while _sink_rows() == 0:
+        while not _w0_lag():             # no snapshot yet, or lag 0
             if time.perf_counter() - t0 > timeout:
-                note = "no reports before kill deadline"
+                note = "worker-0 never served before kill deadline"
                 break
             if sup.drained():
-                note = "topology drained before first sink read"
+                note = "topology drained before worker-0 spooled"
                 break
-            time.sleep(0.05)
+            time.sleep(0.02)
         killed_pid = sup.kill_member("worker-0")
         t_kill = time.perf_counter()
         t_kill_wall = time.time()
@@ -3549,10 +3596,15 @@ def _topology_bench(tpu_ok: bool, timeout: float = 420.0) -> dict:
                                          deaths[0]["t"] - t_kill_wall),
                                      3)
                     break
+                if _w0_exited_clean():
+                    # the kill raced worker-0's own drain exit: no
+                    # death will come, so do not wait out the deadline
+                    note = "worker-0 exited clean before the kill landed"
+                    break
                 time.sleep(0.02)
             # recovery = kill → the RESTARTED worker-0 spooling again
             # (a new pid in its snapshot: matcher rebuilt, serving)
-            while time.perf_counter() - t_kill < timeout:
+            while deaths_seen and time.perf_counter() - t_kill < timeout:
                 doc = sup.snapshots().get("worker-0")
                 if doc is not None and doc.get("pid") not in (None,
                                                               killed_pid):
@@ -5368,7 +5420,8 @@ def _topo_token(_g) -> list:
     latency; None when the arm didn't run), folded identity bit]. The
     fold (mxu-token style) covers every bit the leg recorded:
     aggregation fidelity, cross-pid stitch, and the lease arm's
-    zero-lost + zero-dup + stale-commit-fenced + fault-stats-surfaced —
+    zero-lost + zero-dup + drained-by-exit + stale-commit-fenced +
+    fault-stats-surfaced —
     any recorded False reads 0; an unexercised bit is absent from the
     fold, never vacuous green."""
     pps = _g("topology", "soak", "probes_per_sec_wall")
@@ -5384,6 +5437,7 @@ def _topo_token(_g) -> list:
                         _g("topology", "stitch", "ok"),
                         _g("topology", "lease", "zero_lost_ok"),
                         _g("topology", "lease", "zero_dup_ok"),
+                        _g("topology", "lease", "drained_by_exit_ok"),
                         _g("topology", "lease", "stale_commit_rejected"),
                         _g("topology", "lease", "fault_stats_surfaced"))
             if b is not None]

@@ -569,17 +569,20 @@ class SegmentMatcher:
         from reporter_tpu.utils.profiling import device_trace
 
         tr = tracing.tracer()
-        with self.metrics.stage("match"), device_trace(), \
-                tr.span("match_many", traces=len(traces)):
-            if self.backend == "reference_cpu":
-                out = [self._match_cpu(t) for t in traces]
-            else:
-                out = self._guarded_jax_many(traces, prepared)
-        self.metrics.count("traces", len(traces))
-        probes = sum(len(t.xy) for t in traces)
-        self.metrics.count("probes", probes)
-        if self.quality.enabled and len(traces):
-            self._record_quality(traces, out, probes)
+        # the capture opens first, so the span sees its profiler session
+        # and the capture holds rtpu.match_many over its children
+        with device_trace(), tr.span("match_many", traces=len(traces)):
+            with self.metrics.stage("match"):
+                if self.backend == "reference_cpu":
+                    out = [self._match_cpu(t) for t in traces]
+                else:
+                    out = self._guarded_jax_many(traces, prepared)
+            self.metrics.count("traces", len(traces))
+            probes = sum(len(t.xy) for t in traces)
+            self.metrics.count("probes", probes)
+            if self.quality.enabled and len(traces):
+                with tr.span("quality"):
+                    self._record_quality(traces, out, probes)
         return out
 
     def _record_quality(self, traces: Sequence[Trace], result,
@@ -826,6 +829,10 @@ class SegmentMatcher:
         Pure host bookkeeping — the first half of the round-20
         prepare/dispatch seam (see PreparedSlice)."""
         self._require_staged()
+        with tracing.tracer().span("plan"):
+            return self._plan(traces)
+
+    def _plan(self, traces: Sequence[Trace]):
         max_b = _BUCKETS[-1]
         # Traces beyond the largest bucket are decoded in consecutive chunks
         # (each chunk is an independent HMM; at most the segment spanning a
@@ -872,34 +879,35 @@ class SegmentMatcher:
 
         B = len(ws)
         xys = [work[w][2] for w in ws]
-        # Quantized infeed (half the host→device bytes): i16 0.25 m
-        # offsets from per-trace origins, unless some trace spans
-        # beyond the i16 range (±8.19 km from its first point);
-        # preferred form is i8 per-step DELTAS of the i16 quanta —
-        # integer diffs cumsum back to the exact same absolutes on
-        # device, so it is bit-identical to the i16 path at half the
-        # bytes. The mode decision + buffer fill is the prepare
-        # entry (native C pass, or the byte-identical numpy form).
-        prep = native_prepare.prepare_slice(xys, b)
-        if prep is None:
-            prep = native_prepare.prepare_slice_python(xys, b)
-            self.metrics.count("prepare_python_total")
-        else:
-            self.metrics.count("prepare_native_total")
-        mode, pts, lens, origins, payload = prep
-        # Per-point GPS accuracy → emission distance scaling (see
-        # ops/match.match_traces). None for accuracy-less slices: the
-        # scale-free executable is traced separately, so the common
-        # case pays neither transfer nor compute for the feature.
-        scale = None
-        if any(traces[work[w][0]].accuracy is not None for w in ws):
-            scale = np.ones((B, b), np.float32)
-            for r, w in enumerate(ws):
-                i, lo, xy = work[w]
-                a = traces[i].accuracy
-                if a is not None:
-                    scale[r] = _accuracy_scale(
-                        a[lo:lo + len(xy)], self.params.sigma_z, b)
+        with tracing.tracer().span("prepare"):
+            # Quantized infeed (half the host→device bytes): i16 0.25 m
+            # offsets from per-trace origins, unless some trace spans
+            # beyond the i16 range (±8.19 km from its first point);
+            # preferred form is i8 per-step DELTAS of the i16 quanta —
+            # integer diffs cumsum back to the exact same absolutes on
+            # device, so it is bit-identical to the i16 path at half the
+            # bytes. The mode decision + buffer fill is the prepare
+            # entry (native C pass, or the byte-identical numpy form).
+            prep = native_prepare.prepare_slice(xys, b)
+            if prep is None:
+                prep = native_prepare.prepare_slice_python(xys, b)
+                self.metrics.count("prepare_python_total")
+            else:
+                self.metrics.count("prepare_native_total")
+            mode, pts, lens, origins, payload = prep
+            # Per-point GPS accuracy → emission distance scaling (see
+            # ops/match.match_traces). None for accuracy-less slices: the
+            # scale-free executable is traced separately, so the common
+            # case pays neither transfer nor compute for the feature.
+            scale = None
+            if any(traces[work[w][0]].accuracy is not None for w in ws):
+                scale = np.ones((B, b), np.float32)
+                for r, w in enumerate(ws):
+                    i, lo, xy = work[w]
+                    a = traces[i].accuracy
+                    if a is not None:
+                        scale[r] = _accuracy_scale(
+                            a[lo:lo + len(xy)], self.params.sigma_z, b)
         return PreparedSlice(b, list(ws), mode, pts, lens, origins,
                              payload, scale)
 
@@ -908,11 +916,14 @@ class SegmentMatcher:
         EXISTING entries (`ops.match.wire_from_*` via self._wire) — the
         seam adds no wire fork, only a submission boundary. Returns the
         in-flight wire device array (np.asarray harvests it)."""
-        if ps.mode == 2:
-            return self._wire.q8(ps.payload, ps.origins, ps.lens, ps.scale)
-        if ps.mode == 1:
-            return self._wire.q16(ps.payload, ps.origins, ps.lens, ps.scale)
-        return self._wire.f32(ps.pts, ps.lens, ps.scale)
+        with tracing.tracer().span("dispatch"):
+            if ps.mode == 2:
+                return self._wire.q8(ps.payload, ps.origins, ps.lens,
+                                     ps.scale)
+            if ps.mode == 1:
+                return self._wire.q16(ps.payload, ps.origins, ps.lens,
+                                      ps.scale)
+            return self._wire.f32(ps.pts, ps.lens, ps.scale)
 
     def _submit_many(self, traces: Sequence[Trace]):
         """Submit every trace slice to the device (async dispatches).
@@ -977,7 +988,9 @@ class SegmentMatcher:
         # stream back over the link.
         def split_slice(_k, ws, arr):
             # mesh path pads rows to a device-count multiple: drop them
-            edges, offs, starts = unpack_wire(arr[:len(ws)], self._wire_spec)
+            with tracing.tracer().span("unpack"):
+                edges, offs, starts = unpack_wire(arr[:len(ws)],
+                                                  self._wire_spec)
             for r, w in enumerate(ws):
                 i, lo, xy = work[w]
                 T = len(xy)
@@ -1016,7 +1029,7 @@ class SegmentMatcher:
             self.metrics.count("unmatched_points", unmatched)
             if quality_hold is not None:
                 quality_hold["unmatched"] = unmatched
-            with self.metrics.stage("walk"):
+            with self.metrics.stage("walk"), tracing.tracer().span("walk"):
                 return self._walk_decoded(traces, decoded)
 
         with self.metrics.stage("decode"):
@@ -1043,7 +1056,9 @@ class SegmentMatcher:
         self.metrics.count("unmatched_points", unmatched)
         if quality_hold is not None:
             quality_hold["unmatched"] = unmatched
-        return MatchBatch(_merge_columns(slice_cols), len(traces))
+        with tracing.tracer().span("merge"):
+            merged = _merge_columns(slice_cols)
+        return MatchBatch(merged, len(traces))
 
     def walk_wire_columns(self, traces: Sequence[Trace], work,
                           ws: "list[int]", arr: np.ndarray):
@@ -1053,21 +1068,25 @@ class SegmentMatcher:
         the native walker (the columnar product path's precondition)."""
         from reporter_tpu.ops.match import unpack_wire
 
-        # mesh path pads rows to a device-count multiple: drop them
-        edges, offs, starts = unpack_wire(arr[:len(ws)], self._wire_spec)
-        B, T = edges.shape
-        times = np.zeros((B, T), np.float64)
-        pad = 0
-        for r, w in enumerate(ws):
-            i, lo, xy = work[w]
-            times[r, :len(xy)] = traces[i].times[lo:lo + len(xy)]
-            pad += T - len(xy)          # padded tail decodes unmatched
-        unmatched = int((edges < 0).sum()) - pad
-        cols = self._native_walker.walk_columns(
-            edges, offs, starts, times, self.params.backward_slack)
-        # slice row → global trace index (ws is Morton-sorted)
-        row_to_trace = np.asarray([work[w][0] for w in ws], np.int32)
-        return cols._replace(trace=row_to_trace[cols.trace]), unmatched
+        tr = tracing.tracer()
+        with tr.span("walk"):
+            with tr.span("unpack"):
+                # mesh path pads rows to a device-count multiple: drop them
+                edges, offs, starts = unpack_wire(arr[:len(ws)],
+                                                  self._wire_spec)
+            B, T = edges.shape
+            times = np.zeros((B, T), np.float64)
+            pad = 0
+            for r, w in enumerate(ws):
+                i, lo, xy = work[w]
+                times[r, :len(xy)] = traces[i].times[lo:lo + len(xy)]
+                pad += T - len(xy)          # padded tail decodes unmatched
+            unmatched = int((edges < 0).sum()) - pad
+            cols = self._native_walker.walk_columns(
+                edges, offs, starts, times, self.params.backward_slack)
+            # slice row → global trace index (ws is Morton-sorted)
+            row_to_trace = np.asarray([work[w][0] for w in ws], np.int32)
+            return cols._replace(trace=row_to_trace[cols.trace]), unmatched
 
     def _walk_decoded(self, traces: Sequence[Trace],
                       decoded) -> list[list[SegmentRecord]]:
@@ -1102,12 +1121,18 @@ def _harvest_overlapped(inflight, per_slice) -> None:
     GIL released while the worker processes slice k —
     the shared overlap discipline of the walk and decode paths.
     ``per_slice(k, ws, host_array)`` runs on the worker; exceptions
-    propagate via the futures."""
+    propagate via the futures. Each readback is a ``readback`` span on
+    the caller's thread (it holds the wait for the slice's device work
+    and the transfer)."""
     from concurrent.futures import ThreadPoolExecutor
 
+    tr = tracing.tracer()
     with ThreadPoolExecutor(max_workers=1) as pool:
-        futs = [pool.submit(per_slice, k, ws, np.asarray(wire))
-                for k, (ws, wire) in enumerate(inflight)]
+        futs = []
+        for k, (ws, wire) in enumerate(inflight):
+            with tr.span("readback"):
+                host = np.asarray(wire)
+            futs.append(pool.submit(per_slice, k, ws, host))
         for f in futs:
             f.result()
 

@@ -769,6 +769,7 @@ def _dense_pallas(points, valid, seg_pack: "SegPack | tuple", radius: float,
                 jax.ShapeDtypeStruct((nc * _P, k), jnp.float32),
             ],
             interpret=_INTERPRET,
+            name="candidate_sweep",
         )(*inputs)
 
     def sweep(ids_w):

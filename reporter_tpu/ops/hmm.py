@@ -109,6 +109,7 @@ def _keep_mask_batched(pts, vp, interp_distance: float):
     return keep
 
 
+@jax.named_scope("viterbi")
 def viterbi_decode_batched(cands: CandidateSet, points, valid_pt, tables,
                            sigma_z: float, beta: float,
                            max_route_factor: float, breakage_distance: float,
@@ -124,6 +125,9 @@ def viterbi_decode_batched(cands: CandidateSet, points, valid_pt, tables,
     the batch rides the TPU lane dimension at full width. The vmapped form
     puts K (=8) on lanes — 8/128 occupancy — and measured ~3 ms per scan
     step of almost no arithmetic; batch-last recovers the width.
+
+    Runs under the named scope ``viterbi`` (op metadata only), by which
+    the device trace attributes the scan's ops.
     """
     B, T, K = cands.edge.shape
     ce = jnp.moveaxis(cands.edge, 0, -1)                # [T, K, B]

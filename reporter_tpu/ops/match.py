@@ -43,9 +43,13 @@ def _check_grid_coverage(params: MatcherParams, meta) -> None:
             "index_radius >= radius")
 
 
+@jax.named_scope("candidates")
 def batch_candidates(points, valid_pt, tables, meta,
                      params: MatcherParams) -> CandidateSet:
     """Candidates for a batch of traces: points f32 [B, T, 2] → [B, T, K].
+
+    Runs under the named scope ``candidates`` (op metadata only: the
+    device trace attributes its ops by it; the program is unchanged).
 
     Backend dispatch (params.candidate_backend is static):
       dense — ONE pallas sweep over the flattened [B*T] point batch (the
@@ -230,6 +234,7 @@ def wire_spec(num_edges: int, max_edge_len: float) -> "tuple | None":
     return (ob, q) if q <= 0.5 else None
 
 
+@jax.named_scope("wire_pack")
 def _pack_wire(out: MatchOutput, num_edges: int,
                spec: "tuple | None" = None):
     edge = jnp.maximum(out.edge, 0).astype(jnp.uint32)

@@ -11,7 +11,9 @@ jax.profiler with a uniform entry point:
 The dump is an XPlane/perfetto trace directory loadable in TensorBoard's
 profile plugin or ui.perfetto.dev. `REPORTER_TPU_TRACE_DIR` turns every
 `device_trace(None)` call site on without code changes — the service and
-stream workers wrap their match calls with it.
+stream workers wrap their match calls with it. Named host spans inside
+a capture come from ``utils/tracing.py``: every recorder ``span()``
+lands on the profiler's host plane as ``rtpu.<name>``.
 """
 
 from __future__ import annotations
@@ -62,10 +64,3 @@ def device_trace(trace_dir: "str | None" = None) -> Iterator[None]:
     finally:
         with _trace_lock:
             _trace_active = False
-
-
-def annotate(name: str):
-    """Named sub-span inside a device_trace (TraceAnnotation wrapper)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

@@ -10,7 +10,8 @@ This module makes the decomposition a recorded artifact:
   Span            one named host-side interval (wave/batch-tagged)
   FlightRecorder  a bounded ring of recent spans, thread-safe, cheap,
                   OFF by default (a disabled recorder costs one
-                  attribute read per call site), that can dump a
+                  attribute read and the profiler's enabled check
+                  per call site), that can dump a
                   Chrome-trace-event JSON (perfetto /
                   ``chrome://tracing``-loadable) on demand — and does so
                   AUTOMATICALLY at the round-9 fault sites (dispatch
@@ -40,6 +41,14 @@ Span timestamps are ``time.monotonic`` seconds (the streaming
 pipeline's default clock, so wave spans recorded from pipeline
 timestamps and publisher spans recorded here share one time base);
 dumps convert to the Chrome trace format's microseconds.
+
+While a JAX profiler session is active (``jax.profiler.start_trace``),
+every ``span()`` ALSO lands on the profiler's host plane as a
+``TraceAnnotation`` named ``rtpu.<name>`` — ring on or off — so the
+program's spans share one clock with the device's ops in the XPlane
+trace. The prefix keeps them apart from annotations a caller makes
+around the program (a benchmark's own ``match_many``). With neither
+the ring nor the profiler on, ``span()`` is the shared ``NOOP``.
 """
 
 from __future__ import annotations
@@ -47,6 +56,7 @@ from __future__ import annotations
 import collections
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any
@@ -54,8 +64,8 @@ from typing import Any
 from reporter_tpu.utils import locks
 
 __all__ = ["FlightRecorder", "Span", "tracer", "configure", "span",
-           "post_mortem", "NOOP", "TRACE_KEY", "stamp_record",
-           "trace_id_of"]
+           "post_mortem", "NOOP", "PROFILER_PREFIX", "TRACE_KEY",
+           "stamp_record", "trace_id_of"]
 
 # ---------------------------------------------------------------------------
 # Broker-propagated trace context (round 19). A PRODUCER may stamp a
@@ -152,27 +162,51 @@ class _NoopSpan:
 
 NOOP = _NoopSpan()
 
+PROFILER_PREFIX = "rtpu."
+_annotation_cls = None            # jax.profiler.TraceAnnotation, once seen
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is
+    active, else None. Never imports JAX: without ``jax.profiler``
+    loaded no session can exist. ``is_enabled()`` is the TraceMe's own
+    check (tens of ns)."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        mod = sys.modules.get("jax.profiler")
+        if mod is None:
+            return None
+        cls = _annotation_cls = mod.TraceAnnotation
+    return cls if cls.is_enabled() else None
+
 
 class _SpanCtx:
-    """Context manager that records one Span into the ring on exit."""
+    """Context manager that records one Span into the ring on exit and,
+    given a profiler annotation, holds it open for the same interval."""
 
-    __slots__ = ("_rec", "_name", "_wave", "_args", "_t0")
+    __slots__ = ("_rec", "_name", "_wave", "_args", "_t0", "_ann")
 
     def __init__(self, rec: "FlightRecorder", name: str,
-                 wave: "int | None", args: "dict | None"):
+                 wave: "int | None", args: "dict | None", ann=None):
         self._rec = rec
         self._name = name
         self._wave = wave
         self._args = args
         self._t0 = 0.0
+        self._ann = ann
 
     def __enter__(self) -> "_SpanCtx":
+        if self._ann is not None:
+            self._ann.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc) -> None:
         self._rec.add(self._name, self._t0, time.monotonic(),
                       wave=self._wave, **(self._args or {}))
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
 
 
 class FlightRecorder:
@@ -238,11 +272,16 @@ class FlightRecorder:
 
     def span(self, name: str, wave: "int | None" = None,
              **args):
-        """Context manager recording one span — or the shared no-op when
-        disabled (zero allocation on the off path beyond the call)."""
+        """Context manager recording one span into the ring (when
+        enabled) and onto the profiler's host plane as ``rtpu.<name>``
+        (while a profiler session is active) — or the shared no-op when
+        both are off (zero allocation on that path beyond the call)."""
+        ann = _profiler_annotation()
         if not self.enabled:
-            return NOOP
-        return _SpanCtx(self, name, wave, args or None)
+            return NOOP if ann is None else ann(PROFILER_PREFIX + name)
+        if ann is not None:
+            ann = ann(PROFILER_PREFIX + name)
+        return _SpanCtx(self, name, wave, args or None, ann)
 
     def add(self, name: str, t0: float, t1: float,
             wave: "int | None" = None, **args) -> None:

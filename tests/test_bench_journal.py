@@ -341,6 +341,7 @@ def test_bench_legs_topology_cli(tmp_path):
     assert res["worker_exit_reports_ok"] is True
     lease = res["lease"]
     assert lease["zero_lost_ok"] is True and lease["zero_dup_ok"] is True
+    assert lease["drained_by_exit_ok"] is True       # global drain, exit 0
     assert lease["stale_commit_rejected"] is True    # the zombie probe
     assert lease["fault_stats_surfaced"] is True     # in-worker chaos
     assert lease["deaths"] == 2

@@ -421,6 +421,67 @@ class TestLeaseRunner:
 
 
 # ---------------------------------------------------------------------------
+# the worker CLI in lease mode: global drain + the in-loop stall escape
+
+
+@pytest.mark.parametrize("start", ["alone", "behind_dead_member"])
+def test_lease_worker_cli_drains_tail_and_exits(lease_tiles, tmp_path,
+                                                capsys, start):
+    """``--exit-on-drain`` in lease mode ends the run on the GLOBAL drain
+    (table floors over every partition) on its own, before
+    ``--max-steps``: every record here is a tail below
+    ``flush_min_points``, so only the in-loop force-flush stall escape
+    publishes it and lets the floors reach the end offsets. Behind a
+    dead member, the worker first owns nothing; it must not exit while
+    that member's partitions hold uncommitted records, and drains them
+    once their leases expire."""
+    from reporter_tpu.streaming.__main__ import main
+    from reporter_tpu.streaming.durable_queue import DurableIngestQueue
+
+    tiles = str(tmp_path / "tiles.npz")
+    lease_tiles.save(tiles)
+    broker = str(tmp_path / "broker")
+    q = DurableIngestQueue(broker, 4)
+    probes = [synthesize_probe(lease_tiles, seed=90 + s, num_points=30,
+                               gps_sigma=3.0) for s in range(4)]
+    for r in _records(probes):
+        q.append(r)
+    ends = [q.end_offset(p) for p in range(4)]
+    q.close()
+    cfg = str(tmp_path / "config.json")
+    with open(cfg, "w") as f:
+        json.dump({"streaming": {"num_partitions": 4,
+                                 "flush_min_points": 1000}}, f)
+    lease_dir = str(tmp_path / "leases")
+    if start == "behind_dead_member":
+        ghost = LeaseTable(lease_dir, 4, ttl_s=1.0)
+        for p in range(4):
+            assert ghost.acquire("ghost", p) is not None
+    max_steps = 2000
+    assert main(["--tiles", tiles, "--broker-dir", broker,
+                 "--config", cfg, "--lease-dir", lease_dir,
+                 "--member", "w", "--lease-ttl", "5",
+                 "--poll-interval", "0.01", "--exit-on-drain",
+                 "--max-steps", str(max_steps)]) == 0
+    out = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert out["steps"] < max_steps          # left on the drain, not the cap
+    assert out["lag"] == 0 and out["buffered_points"] == 0
+    assert out["reports"] > 0
+    assert out["lease"]["acquired"] == 4
+    assert out["lease"]["stale_commits"] == 0
+    table = LeaseTable(lease_dir, 4)
+    assert table.floors() == ends                # zero lost
+    acquired_t = [e["t"] for e in table.events()
+                  if e["event"] == "acquire" and e.get("member") == "w"]
+    if start == "behind_dead_member":
+        ghost_t = max(e["t"] for e in table.events()
+                      if e["event"] == "acquire"
+                      and e.get("member") == "ghost")
+        # adopted only after the dead member's leases ran out
+        assert min(acquired_t) >= ghost_t + 1.0
+
+
+# ---------------------------------------------------------------------------
 # concurrency contract (r14 pattern: seed a synthetic violation for the
 # new lock class so the gate guarding it can't rot vacuous-green)
 

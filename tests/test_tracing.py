@@ -154,6 +154,146 @@ def test_post_mortem_dump_count_bounded(recorder, tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# spans on the profiler's host plane (rtpu.<name>) and device scopes
+
+
+def _profiled(trace_dir, body):
+    """Run ``body()`` under a CPU profiler session (Python tracer off)
+    and return the host events named ``rtpu.*`` as (name, start_ns,
+    end_ns, line) — ``line`` tells the recording threads apart."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return _rtpu_events(trace_dir)
+
+
+def _rtpu_events(trace_dir):
+    """The ``rtpu.*`` host events of the one capture under
+    ``trace_dir``, as ``_profiled`` returns them."""
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(str(trace_dir / "**" / "*.xplane.pb"),
+                        recursive=True)
+    out = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for k, line in enumerate(plane.lines):
+            out.extend((e.name, e.start_ns, e.end_ns, (plane.name, k))
+                       for e in line.events
+                       if e.name.startswith(tracing.PROFILER_PREFIX))
+    return out
+
+
+@pytest.mark.parametrize("ring", [True, False])
+def test_span_lands_on_profiler_host_plane(recorder, tmp_path, ring):
+    recorder.configure(enabled=ring, capacity=64)
+
+    def body():
+        ctx = recorder.span("probe_leg", wave=3)
+        assert ctx is not tracing.NOOP      # the profiler alone needs it
+        with ctx:
+            time.sleep(0.002)
+
+    events = _profiled(tmp_path, body)
+    named = [e for e in events if e[0] == "rtpu.probe_leg"]
+    assert len(named) == 1 and named[0][2] - named[0][1] >= 2_000_000
+    ring_names = [s.name for s in recorder.snapshot()]
+    assert ring_names == (["probe_leg"] if ring else [])
+
+
+def test_span_is_noop_with_ring_and_profiler_off(recorder):
+    import jax
+
+    recorder.configure(enabled=False)
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    assert recorder.span("walk") is tracing.NOOP
+    assert tracing.span("readback") is tracing.NOOP
+
+
+MATCH_CHILDREN = ("plan", "prepare", "dispatch", "readback", "walk",
+                  "merge", "quality")
+
+
+def test_match_many_spans_nest_on_the_profiler(recorder, tmp_path,
+                                               trace_tiles, trace_fleet):
+    from reporter_tpu.matcher.api import SegmentMatcher, Trace
+
+    recorder.configure(enabled=False)
+    matcher = SegmentMatcher(trace_tiles, Config(matcher_backend="jax"))
+    assert matcher._native_walker is not None   # the interleaved path
+    traces = [Trace(uuid=p.uuid, xy=p.xy.astype(np.float32),
+                    times=np.asarray(p.times, np.float64))
+              for p in trace_fleet]
+    matcher.match_many(traces)                  # compile outside the trace
+    events = _profiled(tmp_path, lambda: matcher.match_many(traces))
+    (outer,) = [e for e in events if e[0] == "rtpu.match_many"]
+    by_name: dict = {}
+    for e in events:
+        by_name.setdefault(e[0][len(tracing.PROFILER_PREFIX):], []).append(e)
+    for child in MATCH_CHILDREN:
+        assert child in by_name, sorted(by_name)
+        for _, s, t, _ in by_name[child]:
+            assert outer[1] <= s <= t <= outer[2], child
+    # the walk runs on the harvest worker thread, unpack nested inside it
+    assert all(w[3] != outer[3] for w in by_name["walk"])
+    for _, s, t, line in by_name["unpack"]:
+        assert any(w[1] <= s <= t <= w[2] and w[3] == line
+                   for w in by_name["walk"])
+    # a handful of spans per call, never one per trace or per point
+    assert len(events) < 4 * len(MATCH_CHILDREN)
+
+
+def test_match_many_device_trace_capture_holds_the_outer_span(
+        recorder, tmp_path, trace_tiles, trace_fleet, monkeypatch):
+    """The per-call capture (``REPORTER_TPU_TRACE_DIR``) opens before
+    the ``match_many`` span, so that span sees the session and the
+    capture holds ``rtpu.match_many`` around its children."""
+    from reporter_tpu.matcher.api import SegmentMatcher, Trace
+
+    recorder.configure(enabled=False)
+    matcher = SegmentMatcher(trace_tiles, Config(matcher_backend="jax"))
+    traces = [Trace(uuid=p.uuid, xy=p.xy.astype(np.float32),
+                    times=np.asarray(p.times, np.float64))
+              for p in trace_fleet]
+    matcher.match_many(traces)                  # compile outside the trace
+    monkeypatch.setenv("REPORTER_TPU_TRACE_DIR", str(tmp_path))
+    matcher.match_many(traces)
+    events = _rtpu_events(tmp_path)
+    (outer,) = [e for e in events if e[0] == "rtpu.match_many"]
+    inner = [e for e in events if e[0] != "rtpu.match_many"]
+    assert {e[0] for e in inner} >= {"rtpu.dispatch", "rtpu.walk"}
+    assert all(outer[1] <= s <= t <= outer[2] for _, s, t, _ in inner)
+
+
+def test_wire_program_hlo_carries_named_scopes(trace_tiles):
+    import jax.numpy as jnp
+
+    from reporter_tpu.ops.match import match_batch_wire_q8, wire_spec
+
+    ts = trace_tiles
+    params = MatcherParams()
+    rows, T = 2, 16
+    hlo = match_batch_wire_q8.lower(
+        jnp.zeros((rows, T, 2), jnp.int8), jnp.zeros((rows, 2), jnp.float32),
+        jnp.full((rows,), T, jnp.int32), ts.device_tables(
+            params.candidate_backend), ts.meta, params, None,
+        spec=wire_spec(ts.num_edges, float(ts.edge_len.max()))
+    ).compile().as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"', hlo))
+    for scope in ("candidates", "viterbi", "wire_pack"):
+        assert any(f"/{scope}/" in p for p in paths), scope
+
+
+# ---------------------------------------------------------------------------
 # fault-site auto-dumps (the acceptance pair, via faults.py plans)
 
 
