@@ -90,6 +90,41 @@ def emission_costs(cands: CandidateSet, sigma_z: float):
     return jnp.where(cands.valid, c, BIG)
 
 
+ATTR_EDGES = 64    # edges per row of the per-edge attribute table
+
+
+def edge_attr_table(reach_row, edge_len):
+    """i32 [ceil(E / 64), 128]: the per-edge attributes the Viterbi scan
+    looks up, 64 edges a row. Lanes 0-63 of row r hold ``reach_row`` of
+    edges 64r .. 64r+63, lanes 64-127 the bit patterns of their
+    ``edge_len``; the edge count is zero-padded up to a multiple of 64.
+
+    One row gather from this table replaces two per-element gathers from
+    the [E] tables: on a TPU v5e a per-element gather pays ~7 ns an
+    index, a row gather from VMEM ~1.5 ns a 512-byte row."""
+    pad = -reach_row.shape[0] % ATTR_EDGES
+    rows = jnp.pad(reach_row, (0, pad))
+    lens = jnp.pad(jax.lax.bitcast_convert_type(edge_len, jnp.int32),
+                   (0, pad))
+    return jnp.concatenate([rows.reshape(-1, ATTR_EDGES),
+                            lens.reshape(-1, ATTR_EDGES)], axis=1)
+
+
+def edge_attr_lookup(attr, e):
+    """(reach_row[max(e, 0)], edge_len[max(e, 0)]) from ``edge_attr_table``:
+    one row gather, then two lane selects. Each select sums a row in
+    which one lane is nonzero, so both values are the tables' exact bits."""
+    e = jnp.maximum(e, 0)
+    row = attr[e >> 6]                                  # row e // 64
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, row.ndim - 1)
+    j = (e & (ATTR_EDGES - 1))[..., None]
+    # dtype pinned: integer jnp.sum accumulates in the default int width
+    n1 = jnp.sum(jnp.where(lane == j, row, 0), axis=-1, dtype=jnp.int32)
+    bits = jnp.sum(jnp.where(lane == j + ATTR_EDGES, row, 0), axis=-1,
+                   dtype=jnp.int32)
+    return n1, jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def _keep_mask_batched(pts, vp, interp_distance: float):
     """Batch-last keep mask: pts [T, 2, B], vp [T, B] → bool [T, B]."""
     if interp_distance <= 0.0:
@@ -124,7 +159,9 @@ def viterbi_decode_batched(cands: CandidateSet, points, valid_pt, tables,
     [K, B] tensors and each step's K×K transition block is [K, K, B], so
     the batch rides the TPU lane dimension at full width. The vmapped form
     puts K (=8) on lanes — 8/128 occupancy — and measured ~3 ms per scan
-    step of almost no arithmetic; batch-last recovers the width.
+    step of almost no arithmetic; batch-last recovers the width. Each
+    step fetches the previous candidates' reach rows and edge lengths by
+    one row gather from ``edge_attr_table``, built once per call.
 
     Runs under the named scope ``viterbi`` (op metadata only), by which
     the device trace attributes the scan's ops.
@@ -144,21 +181,19 @@ def viterbi_decode_batched(cands: CandidateSet, points, valid_pt, tables,
         jnp.arange(K, dtype=jnp.int32)[:, None], (K, B))
     k_iota = jnp.arange(K, dtype=jnp.int32)
 
-    edge_len = tables["edge_len"]
-    reach_row = tables["reach_row"]
+    attr = edge_attr_table(tables["reach_row"], tables["edge_len"])
     reach_to = tables["reach_to"]
     reach_dist = tables["reach_dist"]
 
     def trans_block(pe, po, pv, e, o, v, gc):
         """[K, K, B] transition costs (mirror of transition_costs)."""
-        e1 = jnp.maximum(pe, 0)                         # [K, B]
         e2 = jnp.maximum(e, 0)
-        n1 = reach_row[e1]                              # edge → reach row
+        n1, len1 = edge_attr_lookup(attr, pe)           # [K, B] each
         rows_to = reach_to[n1]                          # [K, B, M]
         rows_d = reach_dist[n1]
         hit = rows_to[:, None] == e2[None, :, :, None]  # [K, K, B, M]
         gap = jnp.min(jnp.where(hit, rows_d[:, None], BIG), axis=-1)
-        cross = (edge_len[e1] - po)[:, None] + gap + o[None, :]
+        cross = (len1 - po)[:, None] + gap + o[None, :]
         same = ((pe[:, None] == e[None, :])
                 & (o[None, :] >= po[:, None] - backward_slack))
         direct = jnp.maximum(o[None, :] - po[:, None], 0.0)

@@ -240,9 +240,32 @@ class TestInterpolationMask:
 
 
 class TestBatchedViterbi:
-    def test_batched_matches_vmapped(self, tiny_tiles):
+    def test_edge_attr_lookup_exact(self, tiny_tiles):
+        """The scan's one-row-gather lookup returns exactly reach_row[e]
+        and edge_len[e]'s bits for every edge (113 edges: the last row of
+        the attribute table is part padding), and edge 0's for e = -1."""
+        from reporter_tpu.ops.hmm import edge_attr_lookup, edge_attr_table
+
+        ts = tiny_tiles
+        tables = ts.device_tables()
+        E = ts.num_edges
+        assert E % 64
+        attr = edge_attr_table(tables["reach_row"], tables["edge_len"])
+        assert attr.shape == (-(-E // 64), 128)
+        e = np.concatenate([np.arange(E), [-1, 0, E - 1]]).astype(np.int32)
+        n1, len1 = edge_attr_lookup(attr, jnp.asarray(e.reshape(2, -1)))
+        want = np.maximum(e, 0)
+        np.testing.assert_array_equal(
+            np.asarray(n1).ravel(), np.asarray(tables["reach_row"])[want])
+        np.testing.assert_array_equal(
+            np.asarray(len1).ravel().view(np.int32),
+            np.asarray(tables["edge_len"])[want].view(np.int32))
+
+    @pytest.mark.parametrize("tiles", ["tiny_tiles", "sf_tiles"])
+    def test_batched_matches_vmapped(self, tiles, request):
         """viterbi_decode_batched must be bit-identical to
-        vmap(viterbi_decode) — same lattice, batch-last layout."""
+        vmap(viterbi_decode) — same lattice, batch-last layout — on tiles
+        whose edge counts (113, 5,328) are not multiples of 64."""
         import jax
         import jax.numpy as jnp
 
@@ -251,7 +274,8 @@ class TestBatchedViterbi:
         from reporter_tpu.ops.hmm import viterbi_decode, viterbi_decode_batched
         from reporter_tpu.ops.match import batch_candidates
 
-        ts = tiny_tiles
+        ts = request.getfixturevalue(tiles)
+        assert ts.num_edges % 64
         tables = ts.device_tables()
         params = MatcherParams()
         fleet = synthesize_fleet(ts, 7, num_points=40, seed=17)

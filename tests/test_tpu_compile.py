@@ -10,6 +10,7 @@ worker given this file loads the TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -84,6 +85,34 @@ def _compile(ts, one_chip, B, T, params):
     return lowered.compile()
 
 
+def _loop_gathers(hlo: str) -> list[tuple[str, str]]:
+    """(operand shape, slice sizes) of every gather that a ``while`` body
+    of the compiled module reaches, directly or through the computations
+    it calls (fusions, reducers, inner loops)."""
+    comps, types, name = {}, {}, None
+    for line in hlo.splitlines():
+        if not line.startswith(" ") and line.endswith("{"):
+            name = line.split()[line.startswith("ENTRY")].lstrip("%")
+            comps[name] = []
+        elif name and (m := re.match(r"\s+(?:ROOT )?%(\S+) = (\S+) ", line)):
+            types[m.group(1)] = m.group(2).split("{")[0]
+            comps[name].append(line)
+    todo, seen, found = re.findall(r"\bbody=%([\w.-]+)", hlo), set(), []
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        for line in comps[comp]:
+            todo += re.findall(r"(?:calls|to_apply|body|condition)=%([\w.-]+)",
+                               line)
+            m = re.search(r" gather\(%([\w.-]+),.*slice_sizes=\{([\d,]+)\}",
+                          line)
+            if m:
+                found.append((types[m.group(1)], m.group(2)))
+    return found
+
+
 @pytest.mark.parametrize("city,B,arm", [
     ("sf", 512, "block"),
     ("sf", 512, "subcull"),
@@ -96,5 +125,13 @@ def test_match_traces_compiles_for_v5e(city, B, arm, one_chip,
         tiles[city] = compile_network(generate_city(city), CompilerParams())
     params = MatcherParams(candidate_backend="dense", sweep_autotune=False,
                            **ARMS[arm])
-    compiled = _compile(tiles[city], one_chip, B, 120, params)
-    assert "tpu_custom_call" in compiled.as_text()
+    ts = tiles[city]
+    text = _compile(ts, one_chip, B, 120, params).as_text()
+    assert "tpu_custom_call" in text
+    # the Viterbi scan looks the previous candidates' per-edge attributes
+    # up by row gathers from the [ceil(E / 64), 128] table, never by a
+    # per-element gather from an [E] table
+    E = ts.num_edges
+    gathers = _loop_gathers(text)
+    assert not [g for g in gathers if g[0].endswith(f"[{E}]")], gathers
+    assert (f"s32[{-(-E // 64)},128]", "1,128") in gathers, gathers
