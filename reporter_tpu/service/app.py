@@ -220,8 +220,12 @@ class ReporterApp:
         than one batch in flight. Validation errors stay request-scoped
         either way (raised here, before enqueueing).
         """
-        pairs = [_validate_payload(p, self.config.service.mode)
-                 for p in payloads]
+        return self._submit([_validate_payload(p, self.config.service.mode)
+                             for p in payloads])
+
+    def _submit(self, pairs: "list[tuple[str, list[dict]]]") -> list[dict]:
+        """Match validated pairs through the scheduler or the combine
+        leader (``report_many`` after validation)."""
         if self.scheduler is not None:
             return self.scheduler.submit(pairs)
         sub = _Submission(pairs)
@@ -296,17 +300,20 @@ class ReporterApp:
             in_batch[uuid] = merged
             items.append((uuid, merged))
 
-        traces = [
-            Trace.from_json({"uuid": u, "trace": pts}, self.matcher.ts)
-            for u, pts in items
-        ]
-        n_real = len(traces)
-        if self.scheduler is not None:
-            # Shape-bucket padding: the padded tail rides the dispatch and
-            # is dropped below (zip stops at the real items) — results for
-            # real traces are unchanged (batch-composition independence,
-            # tests/test_determinism.py).
-            traces = self.scheduler.pad_traces(traces)
+        # the batch's share of the parse: Trace build and padding (the
+        # request's own share is timed in _parse)
+        with tracing.tracer().span("parse", traces=len(items)):
+            traces = [
+                Trace.from_json({"uuid": u, "trace": pts}, self.matcher.ts)
+                for u, pts in items
+            ]
+            n_real = len(traces)
+            if self.scheduler is not None:
+                # Shape-bucket padding: the padded tail rides the dispatch
+                # and is dropped below (zip stops at the real items) —
+                # results for real traces are unchanged (batch-composition
+                # independence, tests/test_determinism.py).
+                traces = self.scheduler.pad_traces(traces)
         prepared = None
         if (self.config.service.pipeline_prepare
                 and getattr(self.matcher, "supports_prepared", False)
@@ -328,6 +335,7 @@ class ReporterApp:
         else:
             per_trace = self.matcher.match_many(traces)
         dt = time.perf_counter() - t0
+        self.matcher.metrics.observe("batch_match_seconds", dt)
         if len(traces) > n_real:
             # match_many metered the padded list; the /stats north-star
             # counters must credit REAL work only (padding cost is priced
@@ -340,28 +348,30 @@ class ReporterApp:
         all_reports: list[Report] = []
         retains: list[tuple[str, list[dict], float]] = []
         n_traces = n_points = n_reports = 0
+        tr = tracing.tracer()
         t_build0 = time.perf_counter()
-        for (uuid, merged), records in zip(items, per_trace):
-            reports = build_reports(records, self.min_segment_length)
-            all_reports.extend(reports)
-            done = latest_complete_time(records)
-            # Cache retains are DEFERRED to the end: any exception out of
-            # this method must leave the cache unmutated, so the
-            # scheduler's per-submission isolation retry re-merges the
-            # same points the failed combined attempt saw (a mid-loop
-            # retain would silently drop completed segments from the
-            # retried responses). done=None: whole merged trace may
-            # still be mid-segment.
-            retains.append((uuid, merged,
-                            merged[0]["time"] if done is None else done))
-            out.append({
-                "mode": self.config.service.mode,
-                "segments": [r.to_json() for r in records],
-                "reports": [r.to_json() for r in reports],
-            })
-            n_traces += 1
-            n_points += len(merged)
-            n_reports += len(reports)
+        with tr.span("report_build", traces=n_real):
+            for (uuid, merged), records in zip(items, per_trace):
+                reports = build_reports(records, self.min_segment_length)
+                all_reports.extend(reports)
+                done = latest_complete_time(records)
+                # Cache retains are DEFERRED to the end: any exception out
+                # of this method must leave the cache unmutated, so the
+                # scheduler's per-submission isolation retry re-merges the
+                # same points the failed combined attempt saw (a mid-loop
+                # retain would silently drop completed segments from the
+                # retried responses). done=None: whole merged trace may
+                # still be mid-segment.
+                retains.append((uuid, merged,
+                                merged[0]["time"] if done is None else done))
+                out.append({
+                    "mode": self.config.service.mode,
+                    "segments": [r.to_json() for r in records],
+                    "reports": [r.to_json() for r in reports],
+                })
+                n_traces += 1
+                n_points += len(merged)
+                n_reports += len(reports)
         # per-stage series feeding /stats p50s, /metrics histograms, and
         # the bench's service-face latency attribution: a request's wall
         # time decomposes as queue age (scheduler) + match + build +
@@ -369,7 +379,8 @@ class ReporterApp:
         m = self.matcher.metrics
         t_pub0 = time.perf_counter()
         m.observe("report_build_seconds", t_pub0 - t_build0)
-        self.publisher.publish(all_reports)
+        with tr.span("publish", reports=len(all_reports)):
+            self.publisher.publish(all_reports)
         m.observe("publish_seconds", time.perf_counter() - t_pub0)
         for uuid, merged, from_time in retains:   # arrival order: a later
             self.cache.retain(uuid, merged, from_time)   # duplicate wins
@@ -441,7 +452,9 @@ class ReporterApp:
         if method == "POST":
             t0 = time.perf_counter()
             try:
-                return self._dispatch(environ, start_response, method, path)
+                with tracing.tracer().span("request"):
+                    return self._dispatch(environ, start_response, method,
+                                          path)
             finally:
                 self.matcher.metrics.observe(
                     "request_seconds", time.perf_counter() - t0)
@@ -492,16 +505,10 @@ class ReporterApp:
                                    else "no backfill harvest installed")})
                 return _respond(start_response, 200, doc)
             if path == "/report" and method == "POST":
-                body = _read_json(environ)
-                self._bump("requests")
-                return _respond(start_response, 200, self.report_one(body))
+                pairs = self._parse(environ, many=False)
+                return _respond(start_response, 200, self._submit(pairs)[0])
             if path == "/report_many" and method == "POST":
-                body = _read_json(environ)
-                traces = body.get("traces") if isinstance(body, dict) else None
-                if not isinstance(traces, list):
-                    raise BadRequest("payload must be {'traces': [...]}")
-                self._bump("requests")
-                results = self.report_many(traces)
+                results = self._submit(self._parse(environ, many=True))
                 return _respond(start_response, 200, {"results": results})
             if path in ("/report", "/report_many"):
                 return _respond(start_response, 405,
@@ -525,6 +532,31 @@ class ReporterApp:
             self._bump("errors")
             log.exception("unhandled error serving %s %s", method, path)
             return _respond(start_response, 500, {"error": "internal error"})
+
+
+    def _parse(self, environ: dict, many: bool
+               ) -> "list[tuple[str, list[dict]]]":
+        """The request thread's share of the parse: body read, JSON
+        decode, validation. Span ``parse``; series
+        ``http_parse_seconds``, once per request (the batch's share,
+        Trace build and padding, is ``_prefab_validated``'s)."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.tracer().span("parse"):
+                body = _read_json(environ)
+                if not many:
+                    payloads = [body]
+                else:
+                    payloads = (body.get("traces")
+                                if isinstance(body, dict) else None)
+                    if not isinstance(payloads, list):
+                        raise BadRequest("payload must be {'traces': [...]}")
+                self._bump("requests")
+                mode = self.config.service.mode
+                return [_validate_payload(p, mode) for p in payloads]
+        finally:
+            self.matcher.metrics.observe("http_parse_seconds",
+                                         time.perf_counter() - t0)
 
 
 def _read_json(environ: dict) -> Any:

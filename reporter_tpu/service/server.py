@@ -22,6 +22,11 @@ from reporter_tpu.tiles.tileset import TileSet
 
 class ThreadedWSGIServer(ThreadingMixIn, WSGIServer):
     daemon_threads = True
+    # listen backlog: socketserver's default of 5 drops the SYNs of a
+    # fleet's concurrent connects (wsgiref speaks HTTP/1.0, so every
+    # request opens a connection) into the kernel's 1 s / 3 s
+    # retransmits; the kernel clamps this to net.core.somaxconn
+    request_queue_size = 1024
 
 
 class _QuietHandler(WSGIRequestHandler):

@@ -427,3 +427,45 @@ def test_config_json_roundtrip_all_fields():
                                   num_partitions=3),
         matcher_backend="reference_cpu")
     assert Config.from_json(c.to_json()) == c
+
+
+def test_server_backlog_holds_a_fleet_of_connects():
+    """128 clients connecting at once, none served yet (nothing accepts):
+    every connect completes from the listen queue, none waits on the
+    kernel's 1 s SYN retransmit. socketserver's default backlog of 5
+    would leave all but six of them unanswered."""
+    import select
+    import socket
+    import time
+
+    from reporter_tpu.service.server import serve
+
+    def wsgi(environ, start_response):          # never reached: no accept
+        start_response("200 OK", [])
+        return [b""]
+
+    server = serve(wsgi, "127.0.0.1", 0)
+    socks = []
+    try:
+        assert server.request_queue_size >= 128
+        addr = server.server_address
+        for _ in range(128):
+            s = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            s.setblocking(False)
+            s.connect_ex(addr)
+            socks.append(s)
+        pending = {s.fileno(): s for s in socks}
+        done = 0
+        deadline = time.monotonic() + 0.9       # under the 1 s retransmit
+        while pending and time.monotonic() < deadline:
+            _, ready, _ = select.select([], list(pending), [],
+                                        max(0.0, deadline - time.monotonic()))
+            for fd in ready:
+                s = pending.pop(fd)
+                assert s.getsockopt(socket.SOL_SOCKET, socket.SO_ERROR) == 0
+                done += 1
+        assert done == 128, f"{done} of 128 connects completed"
+    finally:
+        for s in socks:
+            s.close()
+        server.server_close()

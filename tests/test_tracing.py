@@ -568,3 +568,41 @@ def test_service_config_trace_env_overrides(monkeypatch):
     assert svc.trace_dir == "/tmp/flight"
     with pytest.raises(ValueError):
         Config(service=ServiceConfig(trace_ring=0)).validate()
+
+
+def test_report_post_spans_and_parse_match_series(recorder, trace_tiles,
+                                                  trace_fleet):
+    """One POST /report through the in-process app with the recorder on:
+    the request span holds the parse (request thread and batch), the
+    report build and the publish; the registry observes the request's
+    parse once and the match once per scheduled batch."""
+    from reporter_tpu.service.app import make_app
+
+    recorder.configure(enabled=True, capacity=256)
+    app = make_app(trace_tiles, Config(
+        matcher_backend="jax",
+        service=ServiceConfig(datastore_url="http://datastore.test/")),
+        transport=lambda url, body: 200)
+    raw = json.dumps(trace_fleet[0].to_report_json()).encode()
+    environ = {"REQUEST_METHOD": "POST", "PATH_INFO": "/report",
+               "CONTENT_LENGTH": str(len(raw)), "wsgi.input": io.BytesIO(raw)}
+    status = []
+    try:
+        body = b"".join(app(environ, lambda s, h: status.append(s)))
+        batches = app.scheduler.stats["batches"]
+    finally:
+        app.close()
+    assert status == ["200 OK"] and "segments" in json.loads(body)
+    spans: dict = {}
+    for s in recorder.snapshot():
+        spans.setdefault(s.name, []).append(s)
+    (req,) = spans["request"]
+    for name in ("parse", "report_build", "publish"):
+        assert name in spans, sorted(spans)
+        for s in spans[name]:
+            assert req.t0 <= s.t0 <= s.t1 <= req.t1, name
+    assert len(spans["parse"]) == 2     # the request's share, the batch's
+    assert len(spans["report_build"]) == len(spans["publish"]) == batches
+    c = app.matcher.metrics.export()["counters"]
+    assert c["http_parse_seconds_count"] == 1
+    assert batches >= 1 and c["batch_match_seconds_count"] == batches
