@@ -1804,7 +1804,7 @@ def _stage_uniform_slice(m, traces):
     from reporter_tpu.matcher.api import _bucket_len
     from reporter_tpu.ops.match import OFFSET_QUANTUM
 
-    B = max(1, m.params.max_device_batch)
+    B = m.slice_rows
     sub = [t for t in traces if len(t.xy) == len(traces[0].xy)][:B]
     T = len(sub[0].xy)
     b = _bucket_len(T)

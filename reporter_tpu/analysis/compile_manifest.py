@@ -82,6 +82,7 @@ def compute_manifest() -> "dict[str, Any]":
         "matcher": {
             "point_buckets": buckets,
             "max_device_batch_default": MatcherParams().max_device_batch,
+            "max_dispatch_rows": api._MAX_DISPATCH_ROWS,
             "wire_entries": ["f32", "q16", "q8"],
             "acc_scale_variants": 2,
             # the [B, T] executable-shape bound per tile per layout: every
@@ -213,7 +214,8 @@ GOLDEN: "dict[str, Any]" = \
  'histogram_scatter': {'cap_rows': 4096},
  'manifest_version': 1,
  'matcher': {'acc_scale_variants': 2,
-             'max_device_batch_default': 4096,
+             'max_device_batch_default': 2048,
+             'max_dispatch_rows': 4096,
              'point_buckets': [16, 32, 64, 128, 256, 512, 1024],
              'wire_entries': ['f32', 'q16', 'q8'],
              'wire_executables_per_tile_bound': 546},

@@ -103,12 +103,18 @@ class MatcherParams:
                                    # GPS noise shifts projections backwards between samples —
                                    # Meili absorbs this via input interpolation, we absorb it
                                    # in the transition model (ops/hmm.route_distance)
-    max_device_batch: int = 4096   # traces per device dispatch. Big enough
-                                   # to amortize per-dispatch link
-                                   # round-trips, small enough that
-                                   # submit-all-then-harvest overlaps device
-                                   # compute with result transfers (measured
-                                   # optimum on a v5e)
+    max_device_batch: int = 2048   # rows per data-parallel device in one
+                                   # dispatch: a bucket group is cut into
+                                   # slices of this many rows times the
+                                   # devices the wire shards rows over,
+                                   # at most 4,096 rows a dispatch
+                                   # (SegmentMatcher.slice_rows), so the
+                                   # host walk of slice k runs while the
+                                   # device works on slice k+1. A group
+                                   # at or under one slice (every /report
+                                   # batch) dispatches whole. 2,048 was
+                                   # the fastest of 512, 1,024 and 2,048
+                                   # on one v5e chip (PERF.md)
     dispatch_timeout_s: float = 0.0  # device-dispatch watchdog: a wedged
                                    # device or transfer HANGS rather than
                                    # erroring, so a wedged dispatch must

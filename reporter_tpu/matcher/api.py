@@ -46,6 +46,12 @@ from reporter_tpu.utils.watchdog import AbandonedThreadWatchdog
 # golden manifest (`python -m reporter_tpu.analysis --update-manifest`).
 _BUCKETS = (16, 32, 64, 128, 256, 512, 1024)
 
+# Most rows one device dispatch carries, however many devices share it:
+# the largest scheduler trace-count rung, so a dispatch shape on any mesh
+# stays inside the pinned compiled-shape universe; on a mesh one host
+# unpacks and walks every device's rows, so its slices stay this small.
+_MAX_DISPATCH_ROWS = 4096
+
 
 class PreparedSlice(NamedTuple):
     """One submit slice after host-side prepare, before dispatch.
@@ -400,6 +406,22 @@ class SegmentMatcher:
         so one constructor argument can never drift from the wire)."""
         wire = getattr(self, "_wire", None)
         return getattr(wire, "mesh", None)
+
+    @property
+    def slice_rows(self) -> int:
+        """Rows one device dispatch carries: ``max_device_batch`` per
+        device the wire shards rows over (1 without a mesh), at most
+        ``_MAX_DISPATCH_ROWS``. The one reader of the knob: ``_plan``
+        cuts each bucket group into slices of this many rows, so a group
+        over one slice overlaps the host walk of a slice with the
+        device's work on the next."""
+        mesh = self.wire_mesh
+        ndev = 1
+        if mesh is not None:
+            from reporter_tpu.parallel.dp_e2e import flat_device_count
+            ndev = flat_device_count(mesh)
+        return min(max(1, self.params.max_device_batch) * ndev,
+                   _MAX_DISPATCH_ROWS)
 
     # ---- fleet residency (device-table paging) ---------------------------
 
@@ -856,10 +878,14 @@ class SegmentMatcher:
         for ws in by_bucket.values():
             arr = np.asarray(ws)
             ws[:] = arr[np.argsort(keys[arr], kind="stable")].tolist()
-        chunk = max(1, self.params.max_device_batch)
+        chunk = self.slice_rows
         sliced = [(b, ws[i:i + chunk])
                   for b, ws in sorted(by_bucket.items())
                   for i in range(0, len(ws), chunk)]
+        if any(len(ws) > chunk for ws in by_bucket.values()):
+            # one plan per match_many call: a call whose harvest overlaps
+            # the device's work on its next slice
+            self.metrics.count("sliced_calls_total")
         return work, sliced
 
     def prepare_submit_slice(self, traces: Sequence[Trace], work,
@@ -916,6 +942,7 @@ class SegmentMatcher:
         EXISTING entries (`ops.match.wire_from_*` via self._wire) — the
         seam adds no wire fork, only a submission boundary. Returns the
         in-flight wire device array (np.asarray harvests it)."""
+        self.metrics.count("dispatch_slices_total")
         with tracing.tracer().span("dispatch"):
             if ps.mode == 2:
                 return self._wire.q8(ps.payload, ps.origins, ps.lens,

@@ -65,8 +65,11 @@ class ServiceOverloaded(RuntimeError):
 # Trace-count rungs: a closed batch's per-point-bucket group is padded up
 # to the next rung so the jitted wire executable's [B, T] shape comes
 # from a small fixed set. Powers of two keep the worst-case padding waste
-# below 50% and the executable population logarithmic; groups beyond the
-# last rung are already sliced to max_device_batch multiples upstream.
+# below 50% and the executable population logarithmic. The matcher cuts
+# a group into dispatches of max_device_batch rows per device, at most
+# 4096 in all (SegmentMatcher.slice_rows): on one chip the 4096 rung
+# reaches the device as two 2048-row slices; every rung up to 2048
+# dispatches whole.
 # The rung set is part of the pinned compiled-shape universe
 # (analysis/compile_manifest.py): changing it requires regenerating the
 # golden manifest (`python -m reporter_tpu.analysis --update-manifest`).

@@ -161,24 +161,58 @@ class TestMatchBatchColumns:
                 r += 1
         assert r == cols.n_records
 
-    def test_slicing_matches_single_slice_run(self, tiny_tiles):
+    @staticmethod
+    def _one_and_sliced(ts, traces, max_device_batch):
+        """(one-slice result, result under ``max_device_batch``, the
+        sliced matcher)."""
         from reporter_tpu.config import Config, MatcherParams
-        from reporter_tpu.matcher.api import SegmentMatcher, Trace
+        from reporter_tpu.matcher.api import SegmentMatcher
+
+        one = SegmentMatcher(ts, Config(matcher_backend="jax"))
+        if one._native_walker is None:
+            pytest.skip("native toolchain unavailable")
+        many = SegmentMatcher(ts, Config(
+            matcher_backend="jax",
+            matcher=MatcherParams(max_device_batch=max_device_batch)))
+        return one.match_many(traces), many.match_many(traces), many
+
+    def test_slicing_matches_single_slice_run(self, tiny_tiles):
+        from reporter_tpu.matcher.api import Trace
         from reporter_tpu.netgen.traces import synthesize_fleet
 
         ts = tiny_tiles
         fleet = synthesize_fleet(ts, 20, num_points=40, seed=34)
         traces = [Trace(uuid=p.uuid, xy=p.xy.astype("float32"),
                         times=p.times) for p in fleet]
-        one = SegmentMatcher(ts, Config(matcher_backend="jax"))
-        if one._native_walker is None:
-            pytest.skip("native toolchain unavailable")
-        many = SegmentMatcher(ts, Config(
-            matcher_backend="jax",
-            matcher=MatcherParams(max_device_batch=4)))
-        ra, rb = one.match_many(traces), many.match_many(traces)
+        ra, rb, _ = self._one_and_sliced(ts, traces, 4)
         for a, b in zip(ra, rb):
             assert [x.to_json() for x in a] == [x.to_json() for x in b]
+
+    @pytest.mark.parametrize("max_device_batch", [1, 3, 8])
+    def test_sliced_two_bucket_batch_is_bit_identical(self, tiny_tiles,
+                                                      max_device_batch):
+        """A batch over two point buckets, cut into slices of a few rows,
+        gives column for column the bytes of the one-slice run: the
+        merge restores trace order across slices and buckets."""
+        from reporter_tpu.matcher.api import Trace
+        from reporter_tpu.netgen.traces import synthesize_fleet
+
+        ts = tiny_tiles
+        fleet = (synthesize_fleet(ts, 13, num_points=40, seed=35)
+                 + synthesize_fleet(ts, 9, num_points=100, seed=36))
+        traces = [Trace(uuid=f"{i}", xy=p.xy.astype("float32"),
+                        times=p.times) for i, p in enumerate(fleet)]
+        # interleave the buckets so trace order differs from plan order
+        traces = traces[::2] + traces[1::2]
+        ra, rb, many = self._one_and_sliced(ts, traces, max_device_batch)
+        assert many.metrics.value("dispatch_slices_total") == \
+            -(-13 // max_device_batch) - (-9 // max_device_batch)
+        a, b = ra.columns, rb.columns
+        assert a.n_records > 0
+        for field in a._fields:
+            x, y = getattr(a, field), getattr(b, field)
+            assert x.dtype == y.dtype, field
+            np.testing.assert_array_equal(x, y, err_msg=field)
 
 
 class TestNativeWalker:
