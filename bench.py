@@ -1714,6 +1714,7 @@ def _sweep_roofline(m, pts: "np.ndarray", per_dispatch_s: float) -> dict:
     before/after utilization comparison lives in every capture."""
     import numpy as np
 
+    from reporter_tpu.matcher.autotune import TunedPlan
     from reporter_tpu.ops import dense_candidates as dc
 
     # lint: allow[staged-layout] 2026-08-04 roofline calibration READS the
@@ -1724,9 +1725,9 @@ def _sweep_roofline(m, pts: "np.ndarray", per_dispatch_s: float) -> dict:
     bbox = np.asarray(m._tables["seg_bbox"])           # [nblocks, 4]
     sub = (np.asarray(m._tables["seg_sub"])
            if "seg_sub" in m._tables else None)
-    subcull = bool(getattr(m.params, "sweep_subcull", True)) and sub is not None
-    mxu = (bool(getattr(m.params, "sweep_mxu", False)) and subcull
-           and "seg_feat" in m._tables)
+    plan = getattr(m, "tuned_plan", None) or TunedPlan()
+    subcull = plan.arm != "block" and sub is not None
+    mxu = plan.arm == "mxu" and subcull and "seg_feat" in m._tables
     stats = _sweep_culling_stats(bbox, sub if subcull else None,
                                  pts.reshape(-1, 2),
                                  float(m.params.search_radius))
@@ -1759,9 +1760,7 @@ def _sweep_roofline(m, pts: "np.ndarray", per_dispatch_s: float) -> dict:
     return {
         "kernel": ("subcull" if subcull else "block")
                   + ("+mxu" if mxu else "")
-                  + ("+bf16" if subcull
-                     and getattr(m.params, "sweep_lowp", "off") == "bf16"
-                     else ""),
+                  + ("+bf16" if subcull and plan.lowp == "bf16" else ""),
         **stats,
         "hbm_bytes_swept": int(bytes_swept),
         "pair_flops": int(flops),
@@ -1823,11 +1822,11 @@ def _stage_uniform_slice(m, traces):
 def _sweep_variants_probe(m, traces, link_rtt: float, K: int = 12,
                           windows: int = 2) -> dict:
     """Same-mood A/B of the sweep kernel arms, the ISSUE-3 discipline:
-    ONE staged slice, three static param variants of the SAME executable
+    ONE staged slice, three static kernel plans of the SAME executable
     family — "subcull" (two-level culling + fused narrow top-K, the r8
     default), "block" (the round-7 whole-block kernel), "mxu" (round 13:
     matmul-form coarse pass on the MXU, bf16 operands — the promoted
-    home of the r8 sweep_lowp="bf16" lever, which now gets its chip
+    home of the r8 lowp="bf16" dtype, which now gets its chip
     numbers here instead of a fourth leg) — dispatched in interleaved
     windows so every arm sees the same link mood. Also asserts the three
     arms' result wires are BYTE-identical on this slice (the exactness
@@ -1839,6 +1838,7 @@ def _sweep_variants_probe(m, traces, link_rtt: float, K: int = 12,
     """
     import numpy as np
 
+    from reporter_tpu.matcher.autotune import TunedPlan
     from reporter_tpu.ops.match import match_batch_wire_q
 
     if "seg_sub" not in m._tables:
@@ -1846,19 +1846,17 @@ def _sweep_variants_probe(m, traces, link_rtt: float, K: int = 12,
     args, _, sub, T = _stage_uniform_slice(m, traces)
     spec = getattr(m, "_wire_spec", None)
     arms = {
-        "subcull": m.params.replace(sweep_subcull=True, sweep_lowp="off",
-                                    sweep_mxu=False),
-        "block": m.params.replace(sweep_subcull=False, sweep_lowp="off",
-                                  sweep_mxu=False),
-        "mxu": m.params.replace(sweep_subcull=True, sweep_lowp="bf16",
-                                sweep_mxu=True),
+        "subcull": TunedPlan(arm="subcull", lowp="off"),
+        "block": TunedPlan(arm="block", lowp="off"),
+        "mxu": TunedPlan(arm="mxu", lowp="bf16"),
     }
     warm = {}
     errors: dict = {}
     for a, p in list(arms.items()):  # compile + one readback per arm,
         try:                         # outside the windows
             warm[a] = np.asarray(match_batch_wire_q(
-                *args, m._tables, m.ts.meta, p, None, spec=spec))
+                *args, m._tables, m.ts.meta, m.params, None, spec=spec,
+                plan=p))
         except Exception as exc:     # an arm that fails to lower must not
             del arms[a]              # sink the whole capture — record it
             errors[a] = repr(exc)[:200]
@@ -1890,8 +1888,8 @@ def _sweep_variants_probe(m, traces, link_rtt: float, K: int = 12,
             m.unstage_tables()
             m.restage_tables(_jax.device_put(host))
             w2 = np.asarray(match_batch_wire_q(
-                *args, m._tables, m.ts.meta, arms[ref_arm], None,
-                spec=spec))
+                *args, m._tables, m.ts.meta, m.params, None, spec=spec,
+                plan=arms[ref_arm]))
             paged_identical = bool(np.array_equal(w2, warm[ref_arm]))
             del w2
         except Exception as exc:
@@ -1908,8 +1906,8 @@ def _sweep_variants_probe(m, traces, link_rtt: float, K: int = 12,
         for a, p in arms.items():
             t0 = time.perf_counter()
             for _ in range(K):
-                wire = match_batch_wire_q(*args, m._tables, m.ts.meta, p,
-                                          None, spec=spec)
+                wire = match_batch_wire_q(*args, m._tables, m.ts.meta,
+                                          m.params, None, spec=spec, plan=p)
             np.asarray(wire)
             dt = max((time.perf_counter() - t0 - link_rtt) / K, 1e-6)
             if best[a] is None or dt < best[a]:
@@ -1951,6 +1949,7 @@ def _sweep_ab_cpu_validate() -> dict:
 
     from reporter_tpu.config import CompilerParams, Config, MatcherParams
     from reporter_tpu.matcher.api import SegmentMatcher
+    from reporter_tpu.matcher.autotune import TunedPlan
     from reporter_tpu.netgen.synthetic import generate_city
     from reporter_tpu.netgen.traces import synthesize_fleet
     from reporter_tpu.ops import dense_candidates as dc
@@ -1965,10 +1964,9 @@ def _sweep_ab_cpu_validate() -> dict:
     pts = np.stack([p.xy for p in fleet]).astype(np.float32)
     lens = np.full(len(fleet), pts.shape[1], np.int32)
     arms = {
-        "subcull": base.replace(sweep_subcull=True, sweep_lowp="off"),
-        "block": base.replace(sweep_subcull=False, sweep_lowp="off"),
-        "mxu": base.replace(sweep_subcull=True, sweep_lowp="bf16",
-                            sweep_mxu=True),
+        "subcull": TunedPlan(arm="subcull", lowp="off"),
+        "block": TunedPlan(arm="block", lowp="off"),
+        "mxu": TunedPlan(arm="mxu", lowp="bf16"),
     }
     wires: dict = {}
     errors: dict = {}
@@ -1980,7 +1978,7 @@ def _sweep_ab_cpu_validate() -> dict:
             try:
                 wires[a] = np.asarray(match_batch_wire(
                     jnp.asarray(pts), jnp.asarray(lens), m._tables,
-                    ts.meta, p, None, spec=None))
+                    ts.meta, base, None, spec=None, plan=p))
             except Exception as exc:
                 errors[a] = repr(exc)[:200]
         if "mxu" in wires:
@@ -1989,7 +1987,7 @@ def _sweep_ab_cpu_validate() -> dict:
                 m.restage_tables(jax.device_put(ts.host_tables("dense")))
                 w2 = np.asarray(match_batch_wire(
                     jnp.asarray(pts), jnp.asarray(lens), m._tables,
-                    ts.meta, arms["mxu"], None, spec=None))
+                    ts.meta, base, None, spec=None, plan=arms["mxu"]))
                 paged_identical = bool(np.array_equal(w2, wires["mxu"]))
             except Exception as exc:
                 errors["paging"] = repr(exc)[:200]
@@ -2018,8 +2016,8 @@ def _autotune_probe(m, traces, link_rtt: float, K: int = 8,
     the plan cache), its per-candidate calibration timings, and a
     same-mood tuned-vs-default interleaved A/B on ONE staged slice (the
     sweep_ab window discipline) — the measured value of self-tuning, in
-    every chip capture. Untuned matchers (explicit knobs / timeout
-    degradation) record why instead of a vacuous 1.0x."""
+    every chip capture. Matchers serving the static default (tuner off /
+    timeout degradation) record why instead of a vacuous 1.0x."""
     import numpy as np
 
     from reporter_tpu.matcher import autotune
@@ -2037,27 +2035,23 @@ def _autotune_probe(m, traces, link_rtt: float, K: int = 8,
     }
     if report.get("errors"):
         out["arm_errors"] = report["errors"]
-    if plan is None:
+    if plan is None or not plan.chosen:
         out["note"] = (f"matcher untuned (source="
                        f"{report.get('source')!r}) — no A/B to run")
         return out
     args, _, sub, T = _stage_uniform_slice(m, traces)
     spec = getattr(m, "_wire_spec", None)
-    arms = {
-        "tuned": m.params.replace(**plan.params_overrides()),
-        "default": m.params.replace(
-            **autotune.default_plan().params_overrides()),
-    }
+    arms = {"tuned": plan, "default": autotune.default_plan()}
     for p in arms.values():         # compile + one readback, untimed
-        np.asarray(match_batch_wire_q(*args, m._tables, m.ts.meta, p,
-                                      None, spec=spec))
+        np.asarray(match_batch_wire_q(*args, m._tables, m.ts.meta,
+                                      m.params, None, spec=spec, plan=p))
     best: dict = dict.fromkeys(arms)
     for _ in range(windows):
         for a, p in arms.items():
             t0 = time.perf_counter()
             for _ in range(K):
                 wire = match_batch_wire_q(*args, m._tables, m.ts.meta,
-                                          p, None, spec=spec)
+                                          m.params, None, spec=spec, plan=p)
             np.asarray(wire)
             dt = max((time.perf_counter() - t0 - link_rtt) / K, 1e-6)
             if best[a] is None or dt < best[a]:
@@ -2096,7 +2090,7 @@ def _autotune_cpu_validate() -> dict:
     ts = compile_network(generate_city("tiny", seed=29), CompilerParams())
     cfg = Config(matcher_backend="jax")
     m = SegmentMatcher(ts, cfg)
-    cpu_short_circuit = (m.tuned_plan is None
+    cpu_short_circuit = (m.tuned_plan == autotune.default_plan()
                          and m.tuned_report.get("source") == "cpu")
 
     # synthetic per-candidate cost model (mxu+bf16 fastest, 256 rung
@@ -2618,7 +2612,7 @@ def _near_tie_stats(m, traces, n: int = 400) -> dict:
     pts = np.stack([t.xy for t in sub]).astype(np.float32)
     valid = np.ones(pts.shape[:2], bool)
     c = batch_candidates(jnp.asarray(pts), jnp.asarray(valid), m._tables,
-                         m.ts.meta, m.params)
+                         m.ts.meta, m.params, m.tuned_plan)
     d = np.asarray(c.dist)
     v = np.asarray(c.valid)
     e = np.asarray(c.edge)

@@ -8,7 +8,7 @@ and compile seconds:
   device     jax.devices() must be a TPU — there is no CPU branch
   batch      bayarea-xl tile, 4000 x 120-point traces through
              SegmentMatcher.match_many once per sweep-kernel arm (block,
-             subcull, mxu; autotune off, arm pinned): the three record
+             subcull, mxu; each a pinned kernel plan): the three record
              streams must be identical. Fidelity against the exact CPU
              oracle (bench._oracle_audit) on 200 sf traces — the bench's
              own oracle tile; exact Dijkstra costs ~13 s per trace at
@@ -51,10 +51,11 @@ TINY = dict(city="tiny", traces=12, points=40, oracle_city="tiny",
             stream_vehicles=8, mesh_city="tiny", mesh_traces=12,
             bf_vehicles=16, bf_points=120)
 
+# sweep-kernel arm -> (arm, lowp) of the kernel plan pinned for it
 ARMS = {
-    "block": dict(sweep_subcull=False),
-    "subcull": dict(sweep_subcull=True),
-    "mxu": dict(sweep_subcull=True, sweep_mxu=True),
+    "block": ("block", "off"),
+    "subcull": ("subcull", "off"),
+    "mxu": ("mxu", "off"),
 }
 
 
@@ -139,6 +140,7 @@ def _same_records(a, b) -> bool:
 def phase_batch(size: dict, rehearse: bool, state: dict) -> dict:
     import bench
     from reporter_tpu import native
+    from reporter_tpu.matcher import autotune
     from reporter_tpu.matcher.api import SegmentMatcher
 
     t0 = time.perf_counter()
@@ -150,8 +152,13 @@ def phase_batch(size: dict, rehearse: bool, state: dict) -> dict:
                  "tile_s": tile_s,
                  "native_lib_loaded": native.lib is not None}
     batches = {}
-    for arm, kw in ARMS.items():
-        m = SegmentMatcher(ts, _config(sweep_autotune=False, **kw))
+    for arm, (kernel, lowp) in ARMS.items():
+        plan = autotune.TunedPlan(arm=kernel, lowp=lowp)
+        m = SegmentMatcher(ts, _config(), staged_tables=autotune.pin_plan(
+            ts.device_tables("dense"), plan))
+        if m.tuned_plan != plan:
+            raise AssertionError(f"arm {arm}: pinned {plan.label}, "
+                                 f"served {m.tuned_plan.label}")
         t1 = time.perf_counter()
         batches[arm] = m.match_many(traces)
         out[f"{arm}_first_call_s"] = time.perf_counter() - t1

@@ -159,7 +159,7 @@ def compute_manifest() -> "dict[str, Any]":
             "calibration_executables_per_tile_bound":
                 len(arms) + len(cap_rungs) - 1,
             "staged_member": "tuned_plan",
-            "nj_cap_default": MatcherParams().sweep_nj_cap,
+            "nj_cap_default": autotune.TunedPlan().nj_cap,
         },
         "staged_tables": {
             "layout_version": tileset.STAGED_LAYOUT_VERSION,

@@ -283,16 +283,11 @@ def _entry_args(entry: str):
     return (jax.ShapeDtypeStruct((_B, _T, 2), jnp.int8), origins, lens)
 
 
-def _arm_params(arm: str):
-    from reporter_tpu.config import MatcherParams
+def _arm_plan(arm: str):
+    from reporter_tpu.matcher.autotune import TunedPlan
 
-    p = MatcherParams(candidate_backend="dense")
-    if arm == "block":
-        return p.replace(sweep_subcull=False)
-    if arm == "mxu":
-        # bf16 operands = the MXU arm the bench A/B measures
-        return p.replace(sweep_mxu=True, sweep_lowp="bf16")
-    return p
+    # bf16 operands = the MXU arm the bench A/B measures
+    return TunedPlan(arm=arm, lowp="bf16" if arm == "mxu" else "off")
 
 
 def _layout_spec(layout: str):
@@ -333,18 +328,21 @@ def _trace_case(case: AuditCase, ts, tables, mesh):
 
     impl = {"f32": match.wire_from_f32, "q16": match.wire_from_q16,
             "q8": match.wire_from_q8}[case.entry]
-    params = _arm_params(case.arm)
+    from reporter_tpu.config import MatcherParams
+
+    params = MatcherParams(candidate_backend="dense")
+    plan = _arm_plan(case.arm)
     spec = _layout_spec(case.layout)
     args = _entry_args(case.entry)
     if case.path == "single":
         def fn(tb, *a):
-            return impl(*a, tb, ts.meta, params, None, spec)
+            return impl(*a, tb, ts.meta, params, None, spec, plan)
 
         return jax.make_jaxpr(fn)(tables, *args)
     from reporter_tpu.parallel.dp_e2e import mesh_wire_fn
 
     fn = mesh_wire_fn(mesh, case.entry, ts.meta, params, spec, tables,
-                      has_acc=False)
+                      has_acc=False, plan=plan)
     return jax.make_jaxpr(fn)(*args, tables)
 
 

@@ -15,15 +15,17 @@ from dataclasses import dataclass, field
 from typing import Any
 
 
-# The narrow-grid launch width LADDER (round 17): ``sweep_nj_cap`` may
-# only take these rungs, so the dense sweep's compiled-shape universe
-# stays finite and manifest-pinned (analysis/compile_manifest.py
-# enumerates ladder × kernel arm). Results are EXACT at any rung — hits
+# The narrow-grid launch width LADDER (round 17): a kernel plan's
+# ``nj_cap`` (matcher/autotune.TunedPlan) may only take these rungs, so
+# the dense sweep's compiled-shape universe stays finite and
+# manifest-pinned (analysis/compile_manifest.py enumerates ladder ×
+# kernel arm). Results are EXACT at any rung — hits
 # sort first in the culled id list and the kernel falls back to the
 # full-width launch whenever a chunk's hits exceed the cap (the round-5
 # lax.cond) — so rung choice is a pure perf decision the per-metro
 # autotuner (matcher/autotune.py) measures. Lives HERE (not in
-# ops/dense_candidates) so config stays jax-import-free.
+# ops/dense_candidates) so the tuner's plan encoding stays
+# jax-import-free.
 SWEEP_NJ_CAP_RUNGS = (64, 128, 256)
 
 
@@ -45,57 +47,19 @@ class MatcherParams:
                                    # gather (ops/candidates.py, ~50x faster
                                    # than the sweep on CPU); "auto" picks by
                                    # the active jax backend
-    sweep_subcull: bool = True     # dense sweep: in-kernel sub-block bbox
-                                   # culling + fused narrow top-K (round 8
-                                   # kernel). False = the round-7
-                                   # whole-block kernel, kept for same-run
-                                   # A/B (bench sweep_ab leg). Bit-identical
-                                   # either way (test-asserted).
-    sweep_lowp: str = "off"        # "bf16" = conservative low-precision
-                                   # coarse pair filter with exact f32
-                                   # refinement inside surviving sub-blocks
-                                   # (also bit-identical — the bf16 pass
-                                   # only ever SKIPS provably-out-of-radius
-                                   # slices). "off" = f32 only. With
-                                   # sweep_mxu=True this selects the MXU
-                                   # matmul's operand dtype instead
-                                   # ("bf16" = the MXU's native width).
-    sweep_mxu: bool = False        # dense sweep: matmul-form coarse pair
-                                   # pass on the MXU (round 13 kernel arm)
-                                   # — per surviving sub-slice, one
-                                   # [P,8]x[8,subw] dot over staged
-                                   # quadratic feature rows yields every
-                                   # pair's point-to-LINE distance; exact
-                                   # f32 geometry + top-K run only on
-                                   # slices the coarse pass can't prove
-                                   # empty. Bit-identical to the other
-                                   # kernel arms by construction
-                                   # (test-asserted). Requires
-                                   # sweep_subcull=True. Default off
-                                   # pending chip numbers (bench sweep_ab
-                                   # measures it every run).
-    sweep_nj_cap: int = 128        # dense sweep: narrow-grid launch width
-                                   # (max culled blocks per chunk before
-                                   # the lax.cond falls back to the full-
-                                   # width grid — ops/dense_candidates).
-                                   # Must be a SWEEP_NJ_CAP_RUNGS rung
-                                   # (finite compiled-shape universe);
-                                   # exact at any rung, so the autotuner
-                                   # may retune it per metro.
-    sweep_autotune: bool = True    # per-metro self-tuning (round 17,
-                                   # matcher/autotune.py): at staging
-                                   # time measure real dispatches per
-                                   # (kernel arm, lowp dtype, nj-cap
-                                   # rung) on the metro's own tables and
-                                   # serve the fastest plan — legal
-                                   # because every arm is wire-byte-
-                                   # identical (detail.sweep_ab). Only
-                                   # acts on accelerator backends with
-                                   # the dense sweep resolved and every
-                                   # sweep lever still at its default
-                                   # (explicit knobs ALWAYS win); CPU
-                                   # short-circuits to the grid/auto
-                                   # choice. False = static defaults.
+    sweep_autotune: bool = True    # per-metro self-tuning of the dense
+                                   # sweep's kernel plan (arm, lowp
+                                   # dtype, nj-cap rung; matcher/
+                                   # autotune.py): at staging time serve
+                                   # a cached plan or measure real
+                                   # dispatches on the metro's own
+                                   # tables — legal because every arm is
+                                   # wire-byte-identical. Only measures
+                                   # on accelerator backends with the
+                                   # dense sweep resolved. False = the
+                                   # static default plan. Either way a
+                                   # plan pinned in the staged tables
+                                   # serves (autotune.pin_plan).
     breakage_distance: float = 2000.0  # consecutive points farther apart break the HMM chain
     max_route_distance_factor: float = 5.0  # route dist > factor*gc ⇒ transition disallowed
     interpolation_distance: float = 10.0    # points closer than this are interpolated, not matched
@@ -144,59 +108,20 @@ class MatcherParams:
 
     def with_env_overrides(self, env: dict[str, str] | None = None,
                            ) -> "MatcherParams":
-        """Kernel-tuning env overrides (the matcher analog of
+        """Deployment env overrides (the matcher analog of
         ServiceConfig.with_env_overrides): only set variables apply.
-        RTPU_SWEEP_SUBCULL=0|1, RTPU_SWEEP_LOWP=off|bf16 and
-        RTPU_SWEEP_MXU=0|1 flip the dense-sweep kernel levers without a
-        code edit — the on-chip A/B discipline every kernel knob here
-        follows (RTPU_SBLK precedent).
+        RTPU_SWEEP_AUTOTUNE=0|1 turns the per-metro plan tuner off or
+        on; RTPU_DISPATCH_TIMEOUT_S and RTPU_DISPATCH_FALLBACK set the
+        dispatch watchdog. Values are validated strictly: a typo raises
+        instead of silently serving the default.
         """
         e = os.environ if env is None else env
         kw: dict[str, Any] = {}
-        # validate HERE, strictly: overrides apply after Config.validate()
-        # in SegmentMatcher, and a typo'd lever that silently fell back to
-        # its default would make an on-chip A/B measure an arm against
-        # itself and record a bogus 1.0x
-        # tracing.env_flag is THE boolean parse (round-14 env-flag lint);
-        # strict=True keeps the round-8 fail-loudly contract for typos
-        from reporter_tpu.utils.tracing import env_flag
-
-        if "RTPU_SWEEP_SUBCULL" in e:
-            try:
-                kw["sweep_subcull"] = env_flag(e["RTPU_SWEEP_SUBCULL"],
-                                               strict=True)
-            except ValueError:
-                raise ValueError(
-                    f"RTPU_SWEEP_SUBCULL={e['RTPU_SWEEP_SUBCULL']!r}: "
-                    "use 0/1") from None
-        if "RTPU_SWEEP_LOWP" in e:
-            lowp = e["RTPU_SWEEP_LOWP"] or "off"
-            if lowp not in ("off", "bf16"):
-                raise ValueError(
-                    f"RTPU_SWEEP_LOWP={lowp!r}: use 'off' or 'bf16'")
-            kw["sweep_lowp"] = lowp
-        if "RTPU_SWEEP_MXU" in e:
-            try:
-                kw["sweep_mxu"] = env_flag(e["RTPU_SWEEP_MXU"], strict=True)
-            except ValueError:
-                raise ValueError(
-                    f"RTPU_SWEEP_MXU={e['RTPU_SWEEP_MXU']!r}: "
-                    "use 0/1") from None
-        if "RTPU_NJ_CAP" in e:
-            try:
-                cap = int(e["RTPU_NJ_CAP"])
-            except ValueError:
-                raise ValueError(
-                    f"RTPU_NJ_CAP={e['RTPU_NJ_CAP']!r}: use one of "
-                    f"{SWEEP_NJ_CAP_RUNGS}") from None
-            if cap not in SWEEP_NJ_CAP_RUNGS:
-                # off-ladder caps would grow the compiled-shape universe
-                # past the committed manifest — reject, don't round
-                raise ValueError(
-                    f"RTPU_NJ_CAP={cap}: not a ladder rung "
-                    f"{SWEEP_NJ_CAP_RUNGS}")
-            kw["sweep_nj_cap"] = cap
         if "RTPU_SWEEP_AUTOTUNE" in e:
+            # tracing.env_flag is THE boolean parse (round-14 env-flag
+            # lint); strict=True fails loudly on typos
+            from reporter_tpu.utils.tracing import env_flag
+
             try:
                 kw["sweep_autotune"] = env_flag(e["RTPU_SWEEP_AUTOTUNE"],
                                                 strict=True)
@@ -217,19 +142,7 @@ class MatcherParams:
                     f"RTPU_DISPATCH_FALLBACK={fb!r}: use 'retry' or "
                     "'reference_cpu'")
             kw["dispatch_fallback"] = fb
-        out = dataclasses.replace(self, **kw) if kw else self
-        if out.sweep_lowp == "bf16" and not out.sweep_subcull:
-            # only the two-level kernel implements the low-precision
-            # pass; accepting the combo would silently run plain f32
-            raise ValueError(
-                "sweep_lowp='bf16' requires sweep_subcull=True — the "
-                "whole-block kernel has no low-precision pass")
-        if out.sweep_mxu and not out.sweep_subcull:
-            # the MXU coarse pass rides the sub-slice structure
-            raise ValueError(
-                "sweep_mxu=True requires sweep_subcull=True — the "
-                "whole-block kernel has no matmul coarse pass")
-        return out
+        return dataclasses.replace(self, **kw) if kw else self
 
     @classmethod
     def preset(cls, mode: str) -> "MatcherParams":
@@ -483,24 +396,6 @@ class Config:
         # against the ACTUAL tileset's index_radius happens at trace time
         # (ops/match._check_grid_coverage) — this one guards the common
         # case where one Config drives both compiler and matcher.
-        if self.matcher.sweep_lowp not in ("off", "bf16"):
-            raise ValueError(
-                f"unknown matcher.sweep_lowp {self.matcher.sweep_lowp!r}; "
-                "use 'off' or 'bf16'")
-        if self.matcher.sweep_lowp == "bf16" and not self.matcher.sweep_subcull:
-            raise ValueError(
-                "matcher.sweep_lowp='bf16' requires sweep_subcull=True — "
-                "the whole-block kernel has no low-precision pass")
-        if self.matcher.sweep_mxu and not self.matcher.sweep_subcull:
-            raise ValueError(
-                "matcher.sweep_mxu=True requires sweep_subcull=True — "
-                "the whole-block kernel has no matmul coarse pass")
-        if self.matcher.sweep_nj_cap not in SWEEP_NJ_CAP_RUNGS:
-            raise ValueError(
-                f"matcher.sweep_nj_cap ({self.matcher.sweep_nj_cap}) is "
-                f"not a ladder rung {SWEEP_NJ_CAP_RUNGS} — off-ladder "
-                "caps grow the compiled-shape universe past the "
-                "committed manifest")
         if (self.matcher.candidate_backend == "grid"
                 and self.compiler.index_radius < self.matcher.search_radius):
             raise ValueError(

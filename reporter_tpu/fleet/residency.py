@@ -275,8 +275,8 @@ class FleetResidency:
                     "staged_bytes": m.staged_bytes,
                     "promotions": m.promotions, "demotions": m.demotions,
                     "leases": m.leases, "last_used_seq": m.last_used,
-                    # round 17: the self-tuned dispatch plan serving this
-                    # metro (None = untuned: cold, CPU, or explicit knobs)
+                    # round 17: the kernel plan serving this metro (None
+                    # = no matcher built yet)
                     "tuned_plan": (m.matcher.tuned_plan.label
                                    if m.matcher is not None
                                    and m.matcher.tuned_plan is not None

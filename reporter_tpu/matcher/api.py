@@ -211,16 +211,18 @@ def _dijkstra_route_fn(ts: TileSet, bound: float,
 
 class _LocalWire:
     """Single-device wire dispatch: the three jitted wire entries over
-    tables staged on the default device. Duck-type shared with
+    tables staged on the default device, with the resolved kernel plan
+    as their static. Duck-type shared with
     parallel.dp_e2e.DpWireMatcher (mesh-sharded rows) — _submit_many
     speaks to whichever the matcher was constructed with."""
 
     def __init__(self, tables, meta, params: MatcherParams,
-                 spec: "tuple | None"):
+                 spec: "tuple | None", plan):
         self.tables = tables
         self.meta = meta
         self.params = params
         self.spec = spec
+        self.plan = plan
 
     def f32(self, pts, lens, acc):
         import jax.numpy as jnp
@@ -229,7 +231,7 @@ class _LocalWire:
         return match_batch_wire(
             jnp.asarray(pts), jnp.asarray(lens), self.tables, self.meta,
             self.params, None if acc is None else jnp.asarray(acc),
-            spec=self.spec)
+            spec=self.spec, plan=self.plan)
 
     def q16(self, pts_q, origins, lens, acc):
         import jax.numpy as jnp
@@ -238,7 +240,8 @@ class _LocalWire:
         return match_batch_wire_q(
             jnp.asarray(pts_q), jnp.asarray(origins), jnp.asarray(lens),
             self.tables, self.meta, self.params,
-            None if acc is None else jnp.asarray(acc), spec=self.spec)
+            None if acc is None else jnp.asarray(acc), spec=self.spec,
+            plan=self.plan)
 
     def q8(self, deltas_q, origins, lens, acc):
         import jax.numpy as jnp
@@ -247,7 +250,8 @@ class _LocalWire:
         return match_batch_wire_q8(
             jnp.asarray(deltas_q), jnp.asarray(origins), jnp.asarray(lens),
             self.tables, self.meta, self.params,
-            None if acc is None else jnp.asarray(acc), spec=self.spec)
+            None if acc is None else jnp.asarray(acc), spec=self.spec,
+            plan=self.plan)
 
 
 class SegmentMatcher:
@@ -267,12 +271,10 @@ class SegmentMatcher:
 
         self.ts = tileset
         self.config = (config or Config()).validate()
-        # kernel-tuning env overrides (RTPU_SWEEP_*) apply at construction
-        # so on-chip A/B runs flip sweep levers without a code edit;
-        # params is a jit static, so each variant compiles separately.
-        # The override is mirrored back into self.config so anything that
-        # introspects/serializes the matcher's config sees the levers
-        # that actually compiled.
+        # deployment env overrides (RTPU_SWEEP_AUTOTUNE, RTPU_DISPATCH_*)
+        # apply at construction, mirrored back into self.config so
+        # anything that introspects/serializes the matcher's config sees
+        # the values that serve
         params = self.config.matcher.with_env_overrides()
         if params is not self.config.matcher:
             self.config = _dc.replace(self.config, matcher=params)
@@ -290,9 +292,9 @@ class SegmentMatcher:
         self._quality_tl = threading.local()
         backend = self.config.matcher_backend
         self._native_walker = None
-        # per-metro self-tuned dispatch plan (round 17): resolved below
-        # for the single-device jax path; None everywhere else (mesh /
-        # reference_cpu / CPU short-circuit / explicit knobs)
+        # the dense sweep's kernel plan (round 17, matcher/autotune.py),
+        # resolved below for the jax backend — the one thing that
+        # chooses the kernel arm; None on reference_cpu
         self.tuned_plan = None
         self.tuned_report: dict = {}
         # dispatch-watchdog degradation state (jax backend): the fallback
@@ -351,8 +353,19 @@ class SegmentMatcher:
                 self._tables = (staged_tables if staged_tables is not None
                                 else tileset.device_tables(
                                     self.params.candidate_backend))
+                # per-metro self-tuning (round 17, matcher/autotune.py):
+                # staged-dict plan → on-disk plan cache → a short
+                # bounded calibration of real dispatches on THIS metro's
+                # staged tables — every arm is wire-byte-identical, so
+                # the pick is pure perf. Runs at construction so the
+                # first served batch already rides the tuned
+                # executables; the fleet's first promotion lands here
+                # too (fleet/residency.py copies the plan back into the
+                # host-pinned dict).
+                self.tuned_plan = self._autotune_resolve(wire_params)
                 self._wire = _LocalWire(self._tables, self.ts.meta,
-                                        wire_params, self._wire_spec)
+                                        wire_params, self._wire_spec,
+                                        self.tuned_plan)
             else:
                 if staged_tables is not None:
                     raise ValueError(
@@ -362,23 +375,15 @@ class SegmentMatcher:
                 self._wire = DpWireMatcher(mesh, tileset, wire_params,
                                            self._wire_spec)
                 self._tables = self._wire.tables    # mesh-replicated
+                # the mesh path never tunes: the static default serves
+                self.tuned_plan = self._wire.plan
+                self.tuned_report = {"source": self.tuned_plan.source}
             self._route_fn = reach_route_fn(tileset)
             # Native batch walker (walker.cc): same walk as build_segments
             # with the reach-table route_fn, multithreaded across traces.
             # None ⇒ per-trace Python fallback.
             from reporter_tpu.matcher.native_walk import make_native_walker
             self._native_walker = make_native_walker(tileset)
-            if mesh is None:
-                # per-metro self-tuning (round 17, matcher/autotune.py):
-                # staged-dict plan → on-disk plan cache → a short
-                # bounded calibration of real dispatches on THIS metro's
-                # staged tables — every arm is wire-byte-identical
-                # (detail.sweep_ab), so the pick is pure perf. Runs at
-                # construction so the first served batch already rides
-                # the tuned executables; the fleet's first promotion
-                # lands here too (fleet/residency.py copies the plan
-                # back into the host-pinned dict).
-                self._autotune_resolve(wire_params)
         elif backend == "reference_cpu":
             if staged_tables is not None:
                 raise ValueError(
@@ -468,9 +473,10 @@ class SegmentMatcher:
 
     # ---- per-metro self-tuning (round 17) --------------------------------
 
-    def _autotune_resolve(self, wire_params: MatcherParams) -> None:
-        """Resolve and APPLY this metro's dispatch plan (see
-        matcher/autotune.py for the resolution order). The calibration
+    def _autotune_resolve(self, wire_params: MatcherParams,
+                          ) -> "autotune.TunedPlan":
+        """Resolve this metro's kernel plan (see matcher/autotune.py for
+        the resolution order) and record how. The calibration
         measure times ``CAL_DISPATCHES`` chained ``match_batch_wire_q``
         dispatches per candidate with ONE host sync on a deterministic
         synthetic batch over the metro's own geometry; each candidate
@@ -495,15 +501,15 @@ class SegmentMatcher:
                                  jax.device_put(lens))
                 np.asarray(state["args"][0][0, 0])      # sync the uploads
             args = state["args"]
-            p = wire_params.replace(**plan.params_overrides())
             wire = match_batch_wire_q(*args, self._tables, self.ts.meta,
-                                      p, None, spec=self._wire_spec)
+                                      wire_params, None,
+                                      spec=self._wire_spec, plan=plan)
             np.asarray(wire)        # compile + first readback, untimed
             t0 = _time.perf_counter()
             for _ in range(autotune.CAL_DISPATCHES):
                 wire = match_batch_wire_q(*args, self._tables,
-                                          self.ts.meta, p, None,
-                                          spec=self._wire_spec)
+                                          self.ts.meta, wire_params, None,
+                                          spec=self._wire_spec, plan=plan)
             np.asarray(wire)        # ONE sync for the whole chain
             return (_time.perf_counter() - t0) / autotune.CAL_DISPATCHES
 
@@ -522,30 +528,17 @@ class SegmentMatcher:
                 info.get("device"), errors)
             self.metrics.count("autotune_candidate_errors_total",
                                float(len(errors)))
-        if plan is None or plan.source in ("default", "timeout"):
-            # nothing to apply: the params already ARE the static
-            # default (the degradation target); the report says why
-            return
-        import dataclasses as _dc
-
-        tuned = self.params.replace(**plan.params_overrides())
-        self.params = tuned
-        # mirror into self.config (the env-override discipline: anything
-        # introspecting the matcher's config must see the levers that
-        # actually serve)
-        self.config = _dc.replace(self.config, matcher=tuned)
-        # wire statics follow; watchdog knobs stay stripped (r9)
-        self._wire.params = tuned.replace(dispatch_timeout_s=0.0,
-                                          dispatch_fallback="retry")
-        self.tuned_plan = plan
-        self.metrics.count(f"autotune_{plan.source}_total")
+        if plan.chosen:
+            self.metrics.count(f"autotune_{plan.source}_total")
+        return plan
 
     def tuned_plan_array(self) -> "np.ndarray | None":
         """The resolved plan as the staged-layout ``tuned_plan`` i32
-        member, or None when untuned — the fleet promotion path copies
-        it back into the host-pinned dict so every later promotion pages
-        already-tuned tables (fleet/residency.py)."""
-        if self.tuned_plan is None:
+        member, or None when none was chosen (the static default serves)
+        — the fleet promotion path copies it back into the host-pinned
+        dict so every later promotion pages already-tuned tables
+        (fleet/residency.py)."""
+        if self.tuned_plan is None or not self.tuned_plan.chosen:
             return None
         from reporter_tpu.matcher import autotune
 
@@ -798,7 +791,7 @@ class SegmentMatcher:
         valid[0, :len(xy)] = True
         pj, vj = jnp.asarray(pts), jnp.asarray(valid)
         cands = batch_candidates(pj, vj, self._tables, self.ts.meta,
-                                 self.params)
+                                 self.params, self.tuned_plan)
         p = self.params
         trace_cands = type(cands)(*(x[0] for x in cands))
         if trace.accuracy is not None:

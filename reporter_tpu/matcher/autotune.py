@@ -1,35 +1,42 @@
 """Per-metro self-tuning dispatch plans (round 17, ROADMAP item 1).
 
 Three rounds of dense-sweep perf work (r8 two-level subcull, r13 MXU
-arm, the bf16 lowp lever) left the kernel choice hand-picked by global
-knobs, while the arm/dtype/launch-width balance point is per-tile
-geometry — exactly the filter/refine trade RTNN (arXiv:2201.01366) and
-SeGraM (arXiv:2205.05883) show must be tuned per workload, not fixed.
+arm, the bf16 lowp dtype) left several kernel arms, while the
+arm/dtype/launch-width balance point is per-tile geometry — exactly
+the filter/refine trade RTNN (arXiv:2201.01366) and SeGraM
+(arXiv:2205.05883) show must be tuned per workload, not fixed.
 Because all three arms are wire-BYTE-identical (asserted by
 ``detail.sweep_ab`` through evict→promote paging) and the narrow-grid
 cap is exact at any ladder rung (the round-5 ``lax.cond`` fallback),
 plan choice is a PURE perf decision: measure, pick, persist.
 
 The plan space is finite by construction — ``CANDIDATE_ARMS`` (every
-legal kernel-arm × ``sweep_lowp`` combination) × the
+legal kernel-arm × lowp dtype combination) × the
 ``config.SWEEP_NJ_CAP_RUNGS`` ladder — and the committed compile-shape
 manifest (analysis/compile_manifest.py) enumerates it, so tuning can
 never grow the executable population past the pinned universe.
 
-Resolution order (``resolve_plan``; explicit knobs ALWAYS win, and CPU
-short-circuits to the existing ``candidate_backend="auto"`` grid
-choice):
+A ``TunedPlan`` is the ONE carrier of the kernel choice: the matcher
+resolves it once and hands it to the wire entries as their jit static
+(ops/match.py), which pass its arm, lowp and nj_cap to
+``find_candidates_dense``. Resolution order (``resolve_plan``):
 
-  1. a host-readable ``tuned_plan`` member already riding the staged
-     dict (a pre-tuned dict paged by the fleet, or an external cache);
+  1. a plan chosen earlier and riding the staged dict as a
+     host-readable ``tuned_plan`` member: a pre-tuned dict paged by the
+     fleet, an external cache, or a plan pinned by hand (``pin_plan``,
+     source ``explicit`` — how tests and the chip smoke select an arm).
+     It serves on every backend, CPU interpret included;
   2. the on-disk plan cache, keyed on tile content fingerprint + device
      kind — the fleet pages already-tuned tables without re-measuring;
-  3. a short, bounded calibration: ``CAL_DISPATCHES`` real dispatches
-     per candidate on the metro's OWN staged tables, two phases (every
-     arm at the default rung, then the winning arm across the remaining
-     rungs), each candidate bounded by the shared
+  3. a short, bounded calibration on an accelerator: ``CAL_DISPATCHES``
+     real dispatches per candidate on the metro's OWN staged tables,
+     two phases (every arm at the default rung, then the winning arm
+     across the remaining rungs), each candidate bounded by the shared
      ``AbandonedThreadWatchdog`` so a wedged device degrades to the
-     static default plan instead of hanging promotion.
+     static default plan instead of hanging promotion;
+  4. the static default ``TunedPlan()`` — also what serves with the
+     tuner off, on CPU (the grid gather or interpret timings), and on
+     the mesh path, which never tunes.
 
 The chosen plan persists as the ``tuned_plan`` member of the
 version-tagged staged-layout dict (tiles/tileset.py, layout v3) — an
@@ -58,7 +65,7 @@ __all__ = [
     "PLAN_VERSION", "CANDIDATE_ARMS", "CAL_DISPATCHES", "CAL_TIMEOUT_S",
     "CAL_BATCH_SHAPE", "TunedPlan", "CalibrationAborted", "default_plan",
     "default_plan_array", "plan_array", "plan_from_array", "plan_json",
-    "explicit_knobs", "calibrate", "resolve_plan", "tile_fingerprint",
+    "pin_plan", "calibrate", "resolve_plan", "tile_fingerprint",
     "device_key", "cache_dir", "load_cached_plan", "store_cached_plan",
     "stamp_cached_plan", "calibration_batch",
 ]
@@ -71,11 +78,15 @@ _ARM_NAMES = ("block", "subcull", "mxu")
 _LOWP_NAMES = ("off", "bf16")
 _SOURCE_NAMES = ("default", "measured", "cache", "staged", "timeout",
                  "cpu", "explicit", "off")
+# sources of a plan that was CHOSEN (measured, or pinned by hand), as
+# against the static default served for want of a choice
+_CHOSEN_SOURCES = ("measured", "cache", "staged", "explicit")
 
 # every LEGAL (arm, lowp) combination, in tie-break preference order:
 # the static default arm first, so equal timings keep today's behavior.
 # block has no low-precision pass and the MXU arm's operand dtype is
-# what lowp selects there (config-layer combo validation mirrors this).
+# what lowp selects there. The one check of legal combinations:
+# plan_from_array and load_cached_plan refuse anything else.
 CANDIDATE_ARMS = (
     ("subcull", "off"),
     ("subcull", "bf16"),
@@ -108,14 +119,24 @@ class CalibrationAborted(RuntimeError):
 
 @dataclass(frozen=True)
 class TunedPlan:
-    """One point of the plan space. Defaults == the static defaults
-    (``MatcherParams``'s sweep levers), so ``TunedPlan()`` IS the
-    degradation target."""
+    """One point of the plan space: the dense sweep's kernel arm
+    (``block`` whole-block, ``subcull`` two-level culling, ``mxu``
+    matmul coarse pass), its low-precision dtype and its narrow-grid
+    rung. ``TunedPlan()`` is the static default and the degradation
+    target. ``source`` records how the plan was resolved; it takes no
+    part in equality or hashing, so plans that run the same kernel are
+    the same jit static (one executable, whatever their provenance)."""
 
     arm: str = "subcull"
     lowp: str = "off"
-    nj_cap: int = MatcherParams.sweep_nj_cap
-    source: str = "default"
+    nj_cap: int = 128               # == ops/dense_candidates._NJ_CAP
+    source: str = dataclasses.field(default="default", compare=False)
+
+    @property
+    def chosen(self) -> bool:
+        """Measured, cached, staged or pinned — not the static default
+        served for want of a choice (default/timeout/cpu/off)."""
+        return self.source in _CHOSEN_SOURCES
 
     @property
     def label(self) -> str:
@@ -123,16 +144,6 @@ class TunedPlan:
         candidate key and the summary token's plan slot."""
         tail = "+bf16" if self.lowp == "bf16" else ""
         return f"{self.arm}{tail}@{self.nj_cap}"
-
-    def params_overrides(self) -> "dict[str, object]":
-        """The ``MatcherParams.replace`` kwargs that apply this plan —
-        THE one mapping from plan space to the sweep levers."""
-        return {
-            "sweep_subcull": self.arm != "block",
-            "sweep_lowp": self.lowp,
-            "sweep_mxu": self.arm == "mxu",
-            "sweep_nj_cap": int(self.nj_cap),
-        }
 
 
 def default_plan(source: str = "default") -> TunedPlan:
@@ -180,6 +191,16 @@ def plan_from_array(arr) -> "TunedPlan | None":
     return plan
 
 
+def pin_plan(tables: dict, plan: TunedPlan) -> dict:
+    """A copy of a staged dict whose ``tuned_plan`` member pins ``plan``
+    (source ``explicit``): a matcher built on it serves that plan on
+    every backend and never measures — THE way to select a kernel arm
+    by hand. The member stays host-readable even when the other leaves
+    are device arrays."""
+    return dict(tables, tuned_plan=plan_array(
+        dataclasses.replace(plan, source="explicit")))
+
+
 def plan_json(plan: "TunedPlan | None") -> "dict | None":
     """The bench/occupancy artifact form."""
     if plan is None:
@@ -189,31 +210,11 @@ def plan_json(plan: "TunedPlan | None") -> "dict | None":
 
 
 # ---------------------------------------------------------------------------
-# explicit-knob detection: the tuner only ever fills knobs the operator
-# left at their defaults
-
-_DEFAULTS = MatcherParams()
-
-
-def explicit_knobs(params: MatcherParams) -> bool:
-    """True when any sweep lever was set away from its default (config
-    field or RTPU_SWEEP_* env, which with_env_overrides mirrors into the
-    params) — explicit knobs always win over the tuner. A lever set
-    explicitly TO its default is indistinguishable and tunes; that is
-    the documented contract (pin with ``sweep_autotune=False``)."""
-    return (params.sweep_subcull != _DEFAULTS.sweep_subcull
-            or params.sweep_lowp != _DEFAULTS.sweep_lowp
-            or params.sweep_mxu != _DEFAULTS.sweep_mxu
-            or params.sweep_nj_cap != _DEFAULTS.sweep_nj_cap)
-
-
-# ---------------------------------------------------------------------------
 # the calibration harness
 
 def calibrate(measure: Callable[[TunedPlan], "float | None"],
               rungs: "tuple[int, ...]" = SWEEP_NJ_CAP_RUNGS,
               arms: "tuple[tuple[str, str], ...]" = CANDIDATE_ARMS,
-              default_cap: "int | None" = None,
               ) -> "tuple[TunedPlan, dict]":
     """Pick the fastest legal plan from measured per-candidate times.
 
@@ -231,8 +232,7 @@ def calibrate(measure: Callable[[TunedPlan], "float | None"],
     leads the enumeration), so equal timings keep today's behavior —
     and make selection deterministic under an injected timer.
     """
-    cap0 = int(default_cap) if default_cap is not None \
-        else _DEFAULTS.sweep_nj_cap
+    cap0 = TunedPlan().nj_cap
     if cap0 not in rungs:
         cap0 = rungs[0]
     report: dict = {"candidates": {}, "errors": {}, "measured": 0}
@@ -376,10 +376,10 @@ def stamp_cached_plan(ts, host_tables: dict, params: MatcherParams,
     fleet promotion path deliberately does NOT call this —
     ``device_key()`` touches ``jax.devices()``, which may be the
     process's first backend init, so only call it when a backend is
-    known-alive. No-op when the tuner would not act anyway (explicit
-    knobs / autotune off / grid-only dict)."""
+    known-alive. No-op when the tuner would not act anyway (autotune
+    off / grid-only dict)."""
     if not getattr(params, "sweep_autotune", True) \
-            or explicit_knobs(params) or "tuned_plan" not in host_tables:
+            or "tuned_plan" not in host_tables:
         return None
     try:
         plan = load_cached_plan(tile_fingerprint(ts), device_key(),
@@ -400,18 +400,24 @@ def resolve_plan(params: MatcherParams, ts, tables,
                  directory: "str | None" = None,
                  backend: "str | None" = None,
                  devkey: "str | None" = None,
-                 ) -> "tuple[TunedPlan | None, dict]":
-    """(plan to apply | None, info). None means the tuner does not act
-    (off / explicit knobs / CPU short-circuit / grid backend) and the
-    params serve as-is; ``info["source"]`` always says why.
+                 ) -> "tuple[TunedPlan, dict]":
+    """(plan to serve, info) — the module docstring's resolution order.
+    The plan is always one to serve: the static default when nothing
+    was chosen, with ``source`` (and ``info["source"]``) saying why.
 
     ``measure``/``backend``/``devkey`` are injectable — CPU tests drive
     the full resolution (cache hit, staged plan, watchdog degradation)
     with a synthetic timer and no device."""
+    # 1) a chosen plan already riding the staged dict, on any backend
+    arr = tables.get("tuned_plan") if hasattr(tables, "get") else None
+    staged = plan_from_array(arr)
+    if staged is not None and staged.chosen:
+        source = "explicit" if staged.source == "explicit" else "staged"
+        return (dataclasses.replace(staged, source=source),
+                {"source": source})
+
     if not getattr(params, "sweep_autotune", True):
-        return None, {"source": "off"}
-    if explicit_knobs(params):
-        return None, {"source": "explicit"}
+        return default_plan("off"), {"source": "off"}
     if backend is None:
         import jax
 
@@ -421,17 +427,8 @@ def resolve_plan(params: MatcherParams, ts, tables,
         resolved = "grid" if backend == "cpu" else "dense"
     if resolved != "dense" or backend == "cpu":
         # the CPU short-circuit: the grid gather has no kernel arms, and
-        # interpret-mode timings on a CPU host are meaningless — keep
-        # the existing "auto" choice untouched
-        return None, {"source": "cpu"}
-
-    # 1) a host-readable plan already riding the staged dict
-    arr = tables.get("tuned_plan") if hasattr(tables, "get") else None
-    staged = plan_from_array(arr)
-    if staged is not None and staged.source in ("measured", "cache",
-                                                "staged"):
-        return (dataclasses.replace(staged, source="staged"),
-                {"source": "staged"})
+        # interpret-mode timings on a CPU host are meaningless
+        return default_plan("cpu"), {"source": "cpu"}
 
     fingerprint = tile_fingerprint(ts)
     if devkey is None:
@@ -469,8 +466,7 @@ def resolve_plan(params: MatcherParams, ts, tables,
         return out
 
     t0 = _time.perf_counter()
-    plan, report = calibrate(guarded,
-                             default_cap=params.sweep_nj_cap)
+    plan, report = calibrate(guarded)
     info = {"source": plan.source, "device": devkey,
             "calibration_seconds": round(_time.perf_counter() - t0, 2),
             "calibration_dispatches":
@@ -479,10 +475,7 @@ def resolve_plan(params: MatcherParams, ts, tables,
     if plan.source == "measured":
         _stamp(plan)
         store_cached_plan(plan, report, fingerprint, devkey, directory)
-        return plan, info
-    # timeout / all-failed degradation: serve the static default —
-    # params already ARE the default, so nothing needs applying, but the
-    # plan is returned so callers can record what happened
+    # 4) timeout / all-failed degradation: the static default serves
     return plan, info
 
 

@@ -99,12 +99,10 @@ _INTERPRET = _env_flag(os.environ.get("RTPU_PALLAS_INTERPRET"))
 _P = 256          # points per chunk: halves the (chunks x blocks) launch
 #                   grid vs 128 — measured ~2/5/9% faster on sf/organic/xl
 #                   (interleaved A/B, round 4); 512 loses (looser bboxes)
-_SBLK = int(os.environ.get("RTPU_SBLK", "512"))
-#                   segment columns per block (small: culling granularity;
-#                   512 re-validated post-narrow-grid — the env override
-#                   exists for interleaved A/B tuning, results are exact
+_SBLK = 512       # segment columns per block (small: culling granularity;
+#                   512 re-validated post-narrow-grid; results are exact
 #                   at any block size since the merge is order-independent)
-_SUB = int(os.environ.get("RTPU_SUB", "128"))
+_SUB = 128
 #                   sub-block columns for the IN-KERNEL second culling
 #                   level (round 8): each DMA'd _SBLK block is tested per
 #                   _SUB-column lane-width slice against the chunk's
@@ -121,11 +119,10 @@ _NJ_CAP = 128     # narrow-grid width DEFAULT rung: max culled blocks per
 #                   overhead that cost bayarea-xl ~45% of its dispatch at
 #                   1184 blocks). Round 17: callers may override per
 #                   dispatch via find_candidates_dense(nj_cap=...) —
-#                   MatcherParams.sweep_nj_cap, restricted to the
-#                   config.SWEEP_NJ_CAP_RUNGS ladder so the compiled-
-#                   shape universe stays manifest-pinned; this module
-#                   constant is the rung served when no param rides in
-#                   (and the compile-manifest's committed default).
+#                   the kernel plan's nj_cap (matcher/autotune.TunedPlan),
+#                   restricted to the config.SWEEP_NJ_CAP_RUNGS ladder so
+#                   the compiled-shape universe stays manifest-pinned;
+#                   this module constant is the default plan's rung.
 SPLIT_LEN = 256.0  # long-segment pre-split span (shared with tiles/capacity)
 
 
@@ -916,8 +913,8 @@ def find_candidates_dense(points, seg_pack, radius: float,
     (interpret-mode test-asserted): coarse passes only ever SKIP
     provably-out-of-radius work, refinement is exact f32.
 
-    nj_cap (round 17): the narrow-grid launch width rung
-    (MatcherParams.sweep_nj_cap; None = this module's _NJ_CAP default).
+    nj_cap (round 17): the narrow-grid launch width rung (the kernel
+    plan's nj_cap; None = this module's _NJ_CAP default).
     Exact at any width — the lax.cond full-width fallback is unchanged —
     so the per-metro autotuner may select it freely.
     """

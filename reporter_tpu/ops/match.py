@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from reporter_tpu.config import MatcherParams
+from reporter_tpu.matcher.autotune import TunedPlan
 from reporter_tpu.ops.candidates import CandidateSet, find_candidates_trace
 from reporter_tpu.ops.dense_candidates import find_candidates_dense
 from reporter_tpu.ops.hmm import viterbi_decode_batched
@@ -43,9 +44,13 @@ def _check_grid_coverage(params: MatcherParams, meta) -> None:
             "index_radius >= radius")
 
 
+_DEFAULT_PLAN = TunedPlan()
+
+
 @jax.named_scope("candidates")
 def batch_candidates(points, valid_pt, tables, meta,
-                     params: MatcherParams) -> CandidateSet:
+                     params: MatcherParams,
+                     plan: TunedPlan = _DEFAULT_PLAN) -> CandidateSet:
     """Candidates for a batch of traces: points f32 [B, T, 2] → [B, T, K].
 
     Runs under the named scope ``candidates`` (op metadata only: the
@@ -53,7 +58,9 @@ def batch_candidates(points, valid_pt, tables, meta,
 
     Backend dispatch (params.candidate_backend is static):
       dense — ONE pallas sweep over the flattened [B*T] point batch (the
-              kernel amortizes its segment-block DMA across every trace);
+              kernel amortizes its segment-block DMA across every trace),
+              with the kernel arm, lowp dtype and narrow-grid rung of
+              ``plan`` (matcher/autotune.TunedPlan, static);
       grid  — per-point cell-row gather, vmapped per trace.
     """
     B, T = points.shape[:2]
@@ -70,10 +77,8 @@ def batch_candidates(points, valid_pt, tables, meta,
              tables.get("seg_sub"), tables.get("seg_feat")),
             params.search_radius, params.max_candidates,
             valid=valid_pt.reshape(B * T),
-            subcull=getattr(params, "sweep_subcull", True),
-            lowp=getattr(params, "sweep_lowp", "off"),
-            mxu=getattr(params, "sweep_mxu", False),
-            nj_cap=getattr(params, "sweep_nj_cap", None))
+            subcull=plan.arm != "block", lowp=plan.lowp,
+            mxu=plan.arm == "mxu", nj_cap=plan.nj_cap)
         return CandidateSet(*(x.reshape(B, T, -1) for x in flat))
     if backend != "grid":
         raise ValueError(
@@ -96,7 +101,8 @@ def match_trace(points, valid_pt, tables, meta,
 
 
 def match_traces(points, valid_pt, tables, meta,
-                 params: MatcherParams, acc_scale=None) -> MatchOutput:
+                 params: MatcherParams, acc_scale=None,
+                 plan: TunedPlan = _DEFAULT_PLAN) -> MatchOutput:
     """Match a batch (not jitted — compose under jit/vmap/shard_map):
     points f32 [B, T, 2], valid_pt bool [B, T].
 
@@ -106,8 +112,10 @@ def match_traces(points, valid_pt, tables, meta,
     candidate DISTANCES by σ_z/σ_point implements per-point σ without
     touching the cost model or the wire format (scaling is uniform within
     a point, so top-K candidate selection is unchanged).
+
+    plan: the dense sweep's kernel plan (see batch_candidates).
     """
-    cands = batch_candidates(points, valid_pt, tables, meta, params)
+    cands = batch_candidates(points, valid_pt, tables, meta, params, plan)
     if acc_scale is not None:
         cands = cands._replace(dist=cands.dist * acc_scale[..., None])
     vit = viterbi_decode_batched(
@@ -145,22 +153,24 @@ OFFSET_QUANTUM = 0.25
 
 
 def wire_from_f32(points, lengths, tables: dict[str, Any], meta: TileMeta,
-                  params: MatcherParams, acc_scale=None, spec=None):
+                  params: MatcherParams, acc_scale=None, spec=None,
+                  plan: TunedPlan = _DEFAULT_PLAN):
     """points f32 [B, T, 2], lengths i32 [B] (valid prefix per trace) →
     u16 [B, 2|3, T] wire array; unpack with unpack_wire(). acc_scale: see
     match_traces (None traces a separate, scale-free executable, so
     accuracy-less batches pay nothing). Undecorated body: jit via
     match_batch_wire, or wrap in shard_map (parallel/dp_e2e) — the SAME
-    device program serves both so the sharded product path cannot drift."""
+    device program serves both so the sharded product path cannot drift.
+    plan: the sweep's kernel plan, a jit static like params."""
     T = points.shape[1]
     valid = jnp.arange(T, dtype=jnp.int32)[None, :] < lengths[:, None]
-    out = match_traces(points, valid, tables, meta, params, acc_scale)
+    out = match_traces(points, valid, tables, meta, params, acc_scale, plan)
     return _pack_wire(out, tables["edge_len"].shape[0], spec)
 
 
 def wire_from_q16(points_q, origins, lengths, tables: dict[str, Any],
                   meta: TileMeta, params: MatcherParams, acc_scale=None,
-                  spec=None):
+                  spec=None, plan: TunedPlan = _DEFAULT_PLAN):
     """Quantized-input variant: points_q i16 [B, T, 2] are 0.25 m
     fixed-point offsets from per-trace origins f32 [B, 2] (host→device
     bytes halve vs f32; 0.125 m quantization ≪ sigma_z). Traces spanning
@@ -170,13 +180,14 @@ def wire_from_q16(points_q, origins, lengths, tables: dict[str, Any],
     points = origins[:, None, :] + points_q.astype(jnp.float32) * jnp.float32(
         OFFSET_QUANTUM)
     valid = jnp.arange(T, dtype=jnp.int32)[None, :] < lengths[:, None]
-    out = match_traces(points, valid, tables, meta, params, acc_scale)
+    out = match_traces(points, valid, tables, meta, params, acc_scale, plan)
     return _pack_wire(out, tables["edge_len"].shape[0], spec)
 
 
 def wire_from_q8(deltas_q, origins, lengths, tables: dict[str, Any],
                  meta: TileMeta, params: MatcherParams,
-                 acc_scale=None, spec=None):
+                 acc_scale=None, spec=None,
+                 plan: TunedPlan = _DEFAULT_PLAN):
     """Delta-quantized input: deltas_q i8 [B, T, 2] are the per-step
     DIFFERENCES of the i16 0.25 m quanta (first step 0 — the origin is
     the first point). Integer cumsum reconstructs the i16 absolutes
@@ -191,16 +202,14 @@ def wire_from_q8(deltas_q, origins, lengths, tables: dict[str, Any],
         OFFSET_QUANTUM)
     T = deltas_q.shape[1]
     valid = jnp.arange(T, dtype=jnp.int32)[None, :] < lengths[:, None]
-    out = match_traces(points, valid, tables, meta, params, acc_scale)
+    out = match_traces(points, valid, tables, meta, params, acc_scale, plan)
     return _pack_wire(out, tables["edge_len"].shape[0], spec)
 
 
-match_batch_wire = functools.partial(
-    jax.jit, static_argnames=("meta", "params", "spec"))(wire_from_f32)
-match_batch_wire_q = functools.partial(
-    jax.jit, static_argnames=("meta", "params", "spec"))(wire_from_q16)
-match_batch_wire_q8 = functools.partial(
-    jax.jit, static_argnames=("meta", "params", "spec"))(wire_from_q8)
+_WIRE_STATICS = ("meta", "params", "spec", "plan")
+match_batch_wire = jax.jit(wire_from_f32, static_argnames=_WIRE_STATICS)
+match_batch_wire_q = jax.jit(wire_from_q16, static_argnames=_WIRE_STATICS)
+match_batch_wire_q8 = jax.jit(wire_from_q8, static_argnames=_WIRE_STATICS)
 
 
 # Compact 2-lane format: metros under _COMPACT_WIRE_EDGES directed edges

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from reporter_tpu.config import MatcherParams
+from reporter_tpu.matcher.autotune import TunedPlan
 from reporter_tpu.ops.match import wire_from_f32, wire_from_q8, wire_from_q16
 from reporter_tpu.parallel.compat import shard_map
 from reporter_tpu.tiles.tileset import TileSet
@@ -55,14 +56,16 @@ def flat_device_count(mesh: Mesh) -> int:
 
 
 def mesh_wire_fn(mesh: Mesh, kind: str, meta, params: MatcherParams,
-                 spec: "tuple | None", tables_pytree, has_acc: bool):
+                 spec: "tuple | None", tables_pytree, has_acc: bool,
+                 plan: TunedPlan = TunedPlan()):
     """``jit(shard_map(wire_from_<kind>))`` over ``mesh`` — THE product-
     path program builder. One spelling, two callers: DpWireMatcher's
     dispatch cache below, and the device-contract audit
     (analysis/device_contract.py), which abstractly traces the same
     callable so the audited mesh program can never drift from the served
     one. ``tables_pytree`` only shapes the replicated in_specs tree —
-    ShapeDtypeStructs work as well as placed arrays."""
+    ShapeDtypeStructs work as well as placed arrays. ``plan``: the
+    sweep's kernel plan (a jit static like ``params``)."""
     impl = _IMPLS[kind]
     nargs = _NARGS[kind]
     data = data_pspec(mesh)                  # rows over ALL mesh axes
@@ -71,12 +74,12 @@ def mesh_wire_fn(mesh: Mesh, kind: str, meta, params: MatcherParams,
     if has_acc:
         def local(*a):
             *ins, acc, tbl = a
-            return impl(*ins, tbl, meta, params, acc, spec)
+            return impl(*ins, tbl, meta, params, acc, spec, plan)
         in_specs = (data,) * nargs + (data, tbl_specs)
     else:
         def local(*a):
             *ins, tbl = a
-            return impl(*ins, tbl, meta, params, None, spec)
+            return impl(*ins, tbl, meta, params, None, spec, plan)
         in_specs = (data,) * nargs + (tbl_specs,)
 
     return jax.jit(shard_map(
@@ -87,7 +90,8 @@ def mesh_wire_fn(mesh: Mesh, kind: str, meta, params: MatcherParams,
 class DpWireMatcher:
     """Duck-type of matcher.api._LocalWire: f32/q16/q8 entries taking host
     numpy arrays, returning an inflight device wire array (padded rows
-    possible — harvest slices to the caller's row count)."""
+    possible — harvest slices to the caller's row count). The mesh path
+    never tunes, so its kernel plan is the static default."""
 
     def __init__(self, mesh: Mesh, ts: TileSet, params: MatcherParams,
                  spec: "tuple | None"):
@@ -96,6 +100,7 @@ class DpWireMatcher:
         self.meta = ts.meta
         self.params = params
         self.spec = spec
+        self.plan = TunedPlan()
         # replicated once; stage only the resolved backend's layout (the
         # unused index is the largest table at metro scale)
         self.tables = jax.device_put(
@@ -140,5 +145,5 @@ class DpWireMatcher:
         if cached is None:
             cached = self._fns[key] = mesh_wire_fn(
                 self.mesh, kind, self.meta, self.params, self.spec,
-                self.tables, has_acc)
+                self.tables, has_acc, self.plan)
         return cached

@@ -129,10 +129,7 @@ def make_sharded_matcher(mesh: Mesh, ts: TileSet, params: MatcherParams,
         flat = find_candidates_dense(
             points.reshape(B * T, 2),
             (seg_pack, seg_bbox, seg_sub, seg_feat),
-            radius, k, valid=valid.reshape(B * T),
-            subcull=getattr(params, "sweep_subcull", True),
-            lowp=getattr(params, "sweep_lowp", "off"),
-            mxu=getattr(params, "sweep_mxu", False))
+            radius, k, valid=valid.reshape(B * T))
         # all-gather each shard's K-list over ICI, then K-merge
         ge = jax.lax.all_gather(flat.edge, axis)        # [shards, N, K]
         gd = jax.lax.all_gather(flat.dist, axis)

@@ -11,13 +11,15 @@ The tuner's contract, pinned here:
   - the on-disk plan cache round-trips and a hit SKIPS re-measurement
     (zero measure calls — the fleet re-promotion requirement);
   - a measured/cached plan already riding the staged dict resolves
-    without any measurement;
-  - explicit knobs always win, CPU short-circuits, off is off;
+    without any measurement, and a plan pinned there by hand serves on
+    every backend (CPU interpret included) — the one way to select an
+    arm; a default-stamped dict still calibrates;
+  - CPU short-circuits, off is off;
   - staged-layout v3: pre-v3 dicts refuse loudly at BOTH injection
     seams (SegmentMatcher(staged_tables=), restage_tables);
   - the narrow-grid cap is a validated ladder rung end to end
-    (MatcherParams / RTPU_NJ_CAP / find_candidates_dense), and rung
-    choice stays exact (interpret parity, both cond branches).
+    (plan decoding / find_candidates_dense), and rung choice stays
+    exact (interpret parity, both cond branches).
 """
 
 import json
@@ -82,13 +84,35 @@ def test_plan_from_array_rejects_foreign_leaves():
     bad_combo[1] = 0        # block...
     bad_combo[2] = 1        # ...+bf16: not a legal candidate
     assert autotune.plan_from_array(bad_combo) is None
+    # every illegal arm x lowp combination is refused — the one check
+    # of legal kernel combinations (block has no low-precision pass)
+    for arm in autotune._ARM_NAMES:
+        for lowp in autotune._LOWP_NAMES:
+            arr = autotune.plan_array(
+                TunedPlan(arm=arm, lowp=lowp, source="explicit"))
+            legal = (arm, lowp) in CANDIDATE_ARMS
+            assert (autotune.plan_from_array(arr) is not None) == legal, \
+                (arm, lowp)
 
 
 def test_default_plan_matches_matcher_param_defaults():
-    """TunedPlan() IS the degradation target: its overrides applied to
-    default params must be a no-op."""
-    p = MatcherParams()
-    assert p.replace(**TunedPlan().params_overrides()) == p
+    """TunedPlan() IS the degradation target: it must be the kernel
+    find_candidates_dense runs by default — the two-level subcull arm
+    in f32 at the module's default narrow-grid rung."""
+    import inspect
+
+    from reporter_tpu.ops import dense_candidates as dc
+
+    plan = TunedPlan()
+    assert plan.nj_cap == dc._NJ_CAP
+    assert plan.nj_cap in SWEEP_NJ_CAP_RUNGS
+    defaults = {k: v.default for k, v in inspect.signature(
+        dc.find_candidates_dense).parameters.items()}
+    assert defaults["subcull"] is True and defaults["mxu"] is False
+    assert plan.arm == "subcull"
+    assert plan.lowp == defaults["lowp"] == "off"
+    assert (plan.arm, plan.lowp) == CANDIDATE_ARMS[0]
+    assert plan.source == "default" and not plan.chosen
 
 
 # ---------------------------------------------------------------------------
@@ -100,8 +124,8 @@ def test_calibrate_picks_fastest_and_is_deterministic():
              "subcull@128": 0.8, "block@128": 2.0}
     p1, rep1 = autotune.calibrate(_timer(costs))
     p2, _ = autotune.calibrate(_timer(costs))
-    assert p1 == p2 == TunedPlan(arm="mxu", lowp="bf16", nj_cap=256,
-                                 source="measured")
+    assert p1 == p2 == TunedPlan(arm="mxu", lowp="bf16", nj_cap=256)
+    assert p1.source == p2.source == "measured"
     assert rep1["winner"] == "mxu+bf16@256"
     # phase 1 measured every arm at the default rung, phase 2 only the
     # winner's remaining rungs — the bounded budget
@@ -121,7 +145,8 @@ def test_calibrate_arm_selection_follows_the_timings():
 
 def test_calibrate_ties_break_toward_the_default_arm():
     p, _ = autotune.calibrate(_timer({}))      # every candidate 1.0 ms
-    assert p == TunedPlan(source="measured")   # subcull@128, the default
+    assert p == TunedPlan()                    # subcull@128, the default
+    assert p.source == "measured"
 
 
 def test_calibrate_skips_failing_candidates():
@@ -142,7 +167,7 @@ def test_calibrate_all_failed_degrades_to_default():
         raise RuntimeError("boom")
 
     p, rep = autotune.calibrate(measure)
-    assert p == TunedPlan()                # source "default"
+    assert p == TunedPlan() and p.source == "default"
     assert "static default" in rep["note"]
 
 
@@ -260,23 +285,52 @@ def test_resolve_gates_off_explicit_and_cpu(ts):
         raise AssertionError("tuner acted when gated off")
 
     off = MatcherParams(candidate_backend="dense", sweep_autotune=False)
-    assert autotune.resolve_plan(off, ts, {}, boom, backend="tpu") \
-        == (None, {"source": "off"})
-    for knobs in (dict(sweep_mxu=True, sweep_lowp="bf16"),
-                  dict(sweep_subcull=False),
-                  dict(sweep_lowp="bf16"),
-                  dict(sweep_nj_cap=64)):
-        explicit = MatcherParams(candidate_backend="dense", **knobs)
-        plan, info = autotune.resolve_plan(explicit, ts, {}, boom,
-                                           backend="tpu")
-        assert plan is None and info["source"] == "explicit", knobs
+    plan, info = autotune.resolve_plan(off, ts, {}, boom, backend="tpu")
+    assert info == {"source": "off"}
+    assert plan == TunedPlan() and plan.source == "off"
     # CPU short-circuit: auto resolves to grid, and even explicit dense
     # on a cpu backend must not measure (interpret timings lie)
     for params in (MatcherParams(),
                    MatcherParams(candidate_backend="dense")):
         plan, info = autotune.resolve_plan(params, ts, {}, boom,
                                            backend="cpu")
-        assert plan is None and info["source"] == "cpu"
+        assert info == {"source": "cpu"}
+        assert plan == TunedPlan() and plan.source == "cpu"
+    # a plan pinned in the staged dict serves on every backend and
+    # through every gate, without measuring
+    pinned = autotune.pin_plan({}, TunedPlan(arm="block", nj_cap=64))
+    for params, backend in ((off, "tpu"), (MatcherParams(), "cpu")):
+        plan, info = autotune.resolve_plan(params, ts, pinned, boom,
+                                           backend=backend)
+        assert info == {"source": "explicit"}
+        assert plan.label == "block@64" and plan.source == "explicit"
+
+
+def test_default_stamped_device_tables_still_calibrate(ts, tmp_path):
+    """TileSet.device_tables stamps the static default plan into every
+    staged dict; that stamp is no choice, so an accelerator matcher
+    staged on it (device leaf, or the fleet's host-readable copy of it)
+    must still measure — else every one-chip cell would silently serve
+    the default arm."""
+    tables = ts.device_tables("dense")
+    stamp = autotune.plan_from_array(np.asarray(tables["tuned_plan"]))
+    assert stamp == TunedPlan() and stamp.source == "default"
+    fleet_shape = dict(tables,
+                       tuned_plan=np.asarray(tables["tuned_plan"]))
+    for i, staged in enumerate((tables, fleet_shape)):
+        calls = {"n": 0}
+
+        def counting(plan):
+            calls["n"] += 1
+            return _timer({"mxu@128": 0.1})(plan)
+
+        plan, info = autotune.resolve_plan(
+            MatcherParams(candidate_backend="dense"), ts, staged, counting,
+            directory=str(tmp_path / str(i)), backend="tpu", devkey="v")
+        assert info["source"] == "measured" and plan.source == "measured"
+        assert plan.label == "mxu@128"
+        assert calls["n"] == len(CANDIDATE_ARMS) + len(
+            SWEEP_NJ_CAP_RUNGS) - 1
 
 
 def test_offline_cold_tier_stamp(tmp_path, ts):
@@ -294,11 +348,11 @@ def test_offline_cold_tier_stamp(tmp_path, ts):
     got = autotune.stamp_cached_plan(ts, host, MatcherParams(), d)
     assert got is not None and got.label == plan.label
     assert autotune.plan_from_array(host["tuned_plan"]).label == plan.label
-    # explicit knobs: the hook must not touch the dict
+    # tuner off: the hook must not touch the dict
     host2 = ts.host_tables("dense")
     before = host2["tuned_plan"].copy()
     assert autotune.stamp_cached_plan(
-        ts, host2, MatcherParams(sweep_nj_cap=64), d) is None
+        ts, host2, MatcherParams(sweep_autotune=False), d) is None
     assert np.array_equal(host2["tuned_plan"], before)
 
 
@@ -348,31 +402,73 @@ def test_matcher_cpu_short_circuit(ts):
     from reporter_tpu.matcher.api import SegmentMatcher
 
     m = SegmentMatcher(ts, Config(matcher_backend="jax"))
-    assert m.tuned_plan is None
+    assert m.tuned_plan == TunedPlan() and m.tuned_plan.source == "cpu"
+    assert m._wire.plan is m.tuned_plan
     assert m.tuned_report == {"source": "cpu"}
-    assert m.tuned_plan_array() is None
+    assert m.tuned_plan_array() is None    # nothing chosen to persist
 
 
 def test_matcher_applies_a_resolved_plan(ts, monkeypatch):
-    """When resolution yields a plan, construction applies it to
-    params, the mirrored config, AND the wire statics — the serving
-    path must ride the tuned executables, not just report them."""
+    """When resolution yields a plan, the wire entries take it as their
+    static — the serving path must ride the tuned executables, not just
+    report them — and params/config stay the configured ones."""
     from reporter_tpu.matcher.api import SegmentMatcher
 
     plan = TunedPlan(arm="mxu", lowp="bf16", nj_cap=256, source="cache")
     monkeypatch.setattr(autotune, "resolve_plan",
                         lambda *a, **k: (plan, {"source": "cache"}))
-    m = SegmentMatcher(ts, Config(matcher_backend="jax"))
-    assert m.tuned_plan == plan
-    assert m.params.sweep_mxu and m.params.sweep_lowp == "bf16"
-    assert m.params.sweep_nj_cap == 256
-    assert m.config.matcher == m.params
-    assert m._wire.params.sweep_mxu
+    cfg = Config(matcher_backend="jax")
+    m = SegmentMatcher(ts, cfg)
+    assert m.tuned_plan is plan
+    assert m._wire.plan is plan
+    assert m.params == cfg.matcher and m.config.matcher == m.params
     # watchdog knobs stay stripped from the wire statics (r9)
     assert m._wire.params.dispatch_timeout_s == 0.0
     got = autotune.plan_from_array(m.tuned_plan_array())
     assert got is not None and got.label == plan.label
     assert int(m.metrics.value("autotune_cache_total")) == 1
+
+
+def test_pinned_staged_plan_serves_the_mxu_arm_on_cpu(ts, monkeypatch):
+    """Pinning a plan in the staged dict is how an arm is selected by
+    hand: on CPU (the Pallas interpreter) the matcher serves the MXU arm
+    with bf16 operands, and its records are bit-identical to the
+    default arm's."""
+    import reporter_tpu.ops.dense_candidates as dc
+    from reporter_tpu.matcher.api import SegmentMatcher, Trace
+    from reporter_tpu.netgen.traces import synthesize_fleet
+
+    monkeypatch.setattr(dc, "_INTERPRET", True)
+    kernels = []
+    real = dc._dense_pallas
+
+    def spy(*a, **kw):
+        kernels.append((kw["subcull"], kw["mxu"], kw["lowp"]))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(dc, "_dense_pallas", spy)
+    cfg = Config(matcher_backend="jax",
+                 matcher=MatcherParams(candidate_backend="dense"))
+    mxu = TunedPlan(arm="mxu", lowp="bf16")
+    pinned = SegmentMatcher(ts, cfg, staged_tables=autotune.pin_plan(
+        ts.host_tables("dense"), mxu))
+    assert pinned.tuned_plan == mxu
+    assert pinned.tuned_plan.source == "explicit"
+    assert pinned.tuned_report == {"source": "explicit"}
+    assert pinned._wire.plan == mxu
+    default = SegmentMatcher(ts, cfg)
+    assert default.tuned_plan == TunedPlan()
+
+    traces = [Trace(uuid=p.uuid, xy=p.xy.astype(np.float32),
+                    times=p.times)
+              for p in synthesize_fleet(ts, 4, num_points=24, seed=3)]
+    got = pinned.match_many(traces)
+    assert (True, True, "bf16") in kernels      # the MXU arm traced
+    want = default.match_many(traces)
+    assert got.n_records == want.n_records > 0
+    for f in got.columns._fields:
+        assert np.array_equal(getattr(got.columns, f),
+                              getattr(want.columns, f)), f
 
 
 def test_matcher_surfaces_candidate_errors(ts, monkeypatch, caplog):
@@ -424,19 +520,12 @@ def test_staged_layout_v3_refused_at_both_seams(ts):
 
 
 def test_nj_cap_env_and_validation():
-    p = MatcherParams().with_env_overrides({"RTPU_NJ_CAP": "64"})
-    assert p.sweep_nj_cap == 64
-    with pytest.raises(ValueError, match="ladder rung"):
-        MatcherParams().with_env_overrides({"RTPU_NJ_CAP": "100"})
-    with pytest.raises(ValueError, match="RTPU_NJ_CAP"):
-        MatcherParams().with_env_overrides({"RTPU_NJ_CAP": "lots"})
     p = MatcherParams().with_env_overrides({"RTPU_SWEEP_AUTOTUNE": "0"})
     assert p.sweep_autotune is False
+    assert MatcherParams().with_env_overrides(
+        {"RTPU_SWEEP_AUTOTUNE": "1"}).sweep_autotune is True
     with pytest.raises(ValueError, match="RTPU_SWEEP_AUTOTUNE"):
         MatcherParams().with_env_overrides({"RTPU_SWEEP_AUTOTUNE": "ja"})
-    with pytest.raises(ValueError, match="ladder rung"):
-        Config(matcher=MatcherParams(sweep_nj_cap=96)).validate()
-    Config(matcher=MatcherParams(sweep_nj_cap=256)).validate()
 
 
 def test_nj_cap_rung_interpret_parity(ts, monkeypatch):

@@ -77,6 +77,7 @@ def test_roofline_schema_all_kernels():
     import jax.numpy as jnp
 
     from reporter_tpu.config import MatcherParams
+    from reporter_tpu.matcher.autotune import TunedPlan
 
     bench = _load_bench()
     sp = _tiny_pack()
@@ -86,22 +87,23 @@ def test_roofline_schema_all_kernels():
               "seg_feat": jnp.asarray(sp.feat)}
     pts = np.random.default_rng(7).uniform(0, 900.0, (256, 2)
                                            ).astype(np.float32)
-    for params in (MatcherParams(),
-                   MatcherParams(sweep_subcull=False),
-                   MatcherParams(sweep_lowp="bf16"),
-                   MatcherParams(sweep_mxu=True, sweep_lowp="bf16")):
-        m = SimpleNamespace(_tables=tables, params=params)
+    for plan in (TunedPlan(),
+                 TunedPlan(arm="block"),
+                 TunedPlan(lowp="bf16"),
+                 TunedPlan(arm="mxu", lowp="bf16")):
+        m = SimpleNamespace(_tables=tables, params=MatcherParams(),
+                            tuned_plan=plan)
         out = bench._sweep_roofline(m, pts, per_dispatch_s=0.1)
-        assert ROOFLINE_KEYS <= set(out), params
+        assert ROOFLINE_KEYS <= set(out), plan
         assert out["pair_flops"] <= out["pair_flops_block_level"]
         assert out["select_flops_ceiling"] > 0
-        if params.sweep_subcull:
+        if plan.arm != "block":
             assert out["kernel"].startswith("subcull")
         else:
             assert out["kernel"] == "block"
-        if params.sweep_lowp == "bf16":
+        if plan.lowp == "bf16":
             assert out["kernel"].endswith("+bf16")
-        if params.sweep_mxu:
+        if plan.arm == "mxu":
             # third work level: the matmul coarse pass is counted and
             # compared against the MXU peak, and the feature-row DMA
             # rides the swept bytes

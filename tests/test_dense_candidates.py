@@ -295,7 +295,7 @@ def test_pallas_kernels_interpret_parity(ts, monkeypatch):
          exact r2 test);
       3. bf16 coarse-filter variant (cond lifted — one trace), same
          spread batch: conservative-refinement exactness incl. ties;
-      4. the retained r7 whole-block kernel (sweep_subcull=False), cond
+      4. the retained r7 whole-block kernel (subcull=False), cond
          live — the bench A/B arm stays pinned too;
       5-7. the round-13 MXU arm: narrow branch executing on the tight
          batch (d=0 ties through the matmul coarse pass), full-width

@@ -530,68 +530,19 @@ class TestAccuracy:
 
 
 class TestSweepEnvOverrides:
-    """RTPU_SWEEP_* env levers (round 8): strict parsing, the
-    bf16-requires-subcull invariant, and the SegmentMatcher mirror of
-    the applied override back into self.config — the A/B-capture
-    attributability contract (a typo'd lever must RAISE, never silently
-    measure an arm against itself)."""
-
-    def test_parsing_and_combo_validation(self, monkeypatch):
-        from reporter_tpu.config import MatcherParams
-
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "off")
-        assert MatcherParams().with_env_overrides().sweep_subcull is False
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "1")
-        assert MatcherParams().with_env_overrides().sweep_subcull is True
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "maybe")
-        with pytest.raises(ValueError, match="RTPU_SWEEP_SUBCULL"):
-            MatcherParams().with_env_overrides()
-
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "1")
-        monkeypatch.setenv("RTPU_SWEEP_LOWP", "bf16")
-        assert MatcherParams().with_env_overrides().sweep_lowp == "bf16"
-        monkeypatch.setenv("RTPU_SWEEP_LOWP", "bf-16")
-        with pytest.raises(ValueError, match="RTPU_SWEEP_LOWP"):
-            MatcherParams().with_env_overrides()
-        # the whole-block kernel has no low-precision pass: the combo
-        # must raise instead of silently running plain f32
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "0")
-        monkeypatch.setenv("RTPU_SWEEP_LOWP", "bf16")
-        with pytest.raises(ValueError, match="sweep_subcull"):
-            MatcherParams().with_env_overrides()
-        with pytest.raises(ValueError, match="sweep_subcull"):
-            Config(matcher=MatcherParams(sweep_lowp="bf16",
-                                         sweep_subcull=False)).validate()
-
-    def test_mxu_lever_parsing_and_combo_validation(self, monkeypatch):
-        """RTPU_SWEEP_MXU (round 13): same strict-parse discipline, and
-        the matmul coarse pass rides the sub-slice structure — mxu
-        without subcull must raise at every validation seam."""
-        from reporter_tpu.config import MatcherParams
-
-        assert MatcherParams().sweep_mxu is False       # off pending chip
-        monkeypatch.setenv("RTPU_SWEEP_MXU", "1")
-        assert MatcherParams().with_env_overrides().sweep_mxu is True
-        monkeypatch.setenv("RTPU_SWEEP_MXU", "no")
-        assert MatcherParams().with_env_overrides().sweep_mxu is False
-        monkeypatch.setenv("RTPU_SWEEP_MXU", "maybe")
-        with pytest.raises(ValueError, match="RTPU_SWEEP_MXU"):
-            MatcherParams().with_env_overrides()
-        monkeypatch.setenv("RTPU_SWEEP_MXU", "1")
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "0")
-        with pytest.raises(ValueError, match="sweep_subcull"):
-            MatcherParams().with_env_overrides()
-        monkeypatch.delenv("RTPU_SWEEP_SUBCULL")
-        with pytest.raises(ValueError, match="sweep_subcull"):
-            Config(matcher=MatcherParams(sweep_mxu=True,
-                                         sweep_subcull=False)).validate()
+    """Deployment env overrides (RTPU_SWEEP_AUTOTUNE, RTPU_DISPATCH_*)
+    apply at SegmentMatcher construction and are mirrored back into
+    self.config — the attributability contract: the config a matcher
+    reports is the one that serves."""
 
     def test_matcher_mirrors_override_into_config(self, tiny_tiles,
                                                   monkeypatch):
-        monkeypatch.setenv("RTPU_SWEEP_SUBCULL", "0")
+        monkeypatch.setenv("RTPU_DISPATCH_TIMEOUT_S", "7.5")
         m = SegmentMatcher(tiny_tiles, Config(matcher_backend="jax"))
-        assert m.params.sweep_subcull is False
-        assert m.config.matcher.sweep_subcull is False   # no stale view
-        monkeypatch.delenv("RTPU_SWEEP_SUBCULL")
+        assert m.params.dispatch_timeout_s == 7.5
+        assert m.config.matcher.dispatch_timeout_s == 7.5  # no stale view
+        # host-only watchdog knobs never reach the wire statics (r9)
+        assert m._wire.params.dispatch_timeout_s == 0.0
+        monkeypatch.delenv("RTPU_DISPATCH_TIMEOUT_S")
         m2 = SegmentMatcher(tiny_tiles, Config(matcher_backend="jax"))
-        assert m2.params.sweep_subcull is True
+        assert m2.params.dispatch_timeout_s == 0.0

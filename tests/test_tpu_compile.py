@@ -18,15 +18,16 @@ import numpy as np
 import pytest
 
 from reporter_tpu.config import CompilerParams, MatcherParams
+from reporter_tpu.matcher.autotune import TunedPlan
 from reporter_tpu.netgen.synthetic import generate_city
 from reporter_tpu.ops import dense_candidates as dc
 from reporter_tpu.ops.match import match_traces
 from reporter_tpu.tiles.compiler import compile_network
 
 ARMS = {
-    "block": dict(sweep_subcull=False),
-    "subcull": dict(),
-    "mxu": dict(sweep_mxu=True),
+    "block": TunedPlan(arm="block"),
+    "subcull": TunedPlan(),
+    "mxu": TunedPlan(arm="mxu"),
 }
 
 
@@ -67,7 +68,7 @@ def tiles():
     return {}
 
 
-def _compile(ts, one_chip, B, T, params):
+def _compile(ts, one_chip, B, T, params, plan=TunedPlan()):
     host = ts.host_tables("dense")
     tabs = {k: jax.ShapeDtypeStruct(np.shape(v), np.asarray(v).dtype,
                                     sharding=one_chip)
@@ -76,7 +77,7 @@ def _compile(ts, one_chip, B, T, params):
     val = jax.ShapeDtypeStruct((B, T), jnp.bool_, sharding=one_chip)
 
     def fn(p, v, t):
-        return match_traces(p, v, t, ts.meta, params)
+        return match_traces(p, v, t, ts.meta, params, plan=plan)
 
     # the CPU test host answers default_backend() == "cpu"; the override
     # traces the Pallas program the chip would run
@@ -123,10 +124,9 @@ def test_match_traces_compiles_for_v5e(city, B, arm, one_chip,
                                        no_persistent_cache, tiles):
     if city not in tiles:
         tiles[city] = compile_network(generate_city(city), CompilerParams())
-    params = MatcherParams(candidate_backend="dense", sweep_autotune=False,
-                           **ARMS[arm])
+    params = MatcherParams(candidate_backend="dense", sweep_autotune=False)
     ts = tiles[city]
-    text = _compile(ts, one_chip, B, 120, params).as_text()
+    text = _compile(ts, one_chip, B, 120, params, ARMS[arm]).as_text()
     assert "tpu_custom_call" in text
     # the Viterbi scan looks the previous candidates' per-edge attributes
     # up by row gathers from the [ceil(E / 64), 128] table, never by a
